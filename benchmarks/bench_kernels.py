@@ -240,8 +240,7 @@ def pipeline_rows(rng, *, dry_run: bool = False,
                                                 interpret=True)
         _, pos = join_ops.expand_pairs(lo, counts, use_kernel=True,
                                        interpret=True)
-        join_ops.gather_rows(order, pos, use_kernel=True, interpret=True,
-                             bounded_by_len=True)
+        join_ops.gather_rows(order, pos, on_device=True)
     assert kf.total < ks.total, \
         "fused kernel tier must cross the boundary strictly less than staged"
     rows.append(("kern/pipeline_pallas_transfers", float(kf.total),

@@ -1,8 +1,8 @@
 """Empirical kernel-vs-fallback dispatch tuning.
 
 The join family's auto dispatch is governed by scaling envelopes — the
-quadratic probe-work cap, the expand ownership-test cap, the gather
-VMEM-residency cap (``join/ops.py``). Their defaults are analytical
+quadratic probe-work cap and the expand ownership-test cap
+(``join/ops.py``). Their defaults are analytical
 guesses; this module replaces guesses with measurements on the backend
 that will actually serve: it sweeps each stage's Pallas kernel against the
 fallback tier auto dispatch would otherwise pick (host numpy on CPU, the
@@ -43,16 +43,15 @@ from repro.kernels.join import ops
 # defaults below is the ops module's getters — kept in sync by the tests)
 PROBE_CAP = "REPRO_JOIN_PROBE_WORK_CAP"
 EXPAND_CAP = "REPRO_JOIN_EXPAND_WORK_CAP"
-GATHER_CAP = "REPRO_JOIN_GATHER_RESIDENT_ROWS"
 
-_DEFAULTS = {PROBE_CAP: 1 << 32, EXPAND_CAP: 1 << 32, GATHER_CAP: 1 << 21}
+_DEFAULTS = {PROBE_CAP: 1 << 32, EXPAND_CAP: 1 << 32}
 
 
 @dataclasses.dataclass
 class Measurement:
     """One sweep point: the stage's abstract work size (the quantity the
     envelope caps — compare pairs for the probe, ownership tests for the
-    expand, table rows for the gather) and both tiers' wall time."""
+    expand) and both tiers' wall time."""
     stage: str
     work: int
     kernel_us: float
@@ -161,13 +160,11 @@ def tune_join(*, quick: bool = False,
         sizes = (64, 128) if quick else (256, 1024, 4096)
     on_tpu = dispatch.on_tpu()
     interpret = not on_tpu
-    sweeps: Dict[str, List[Measurement]] = {"probe": [], "expand": [],
-                                            "gather": []}
+    sweeps: Dict[str, List[Measurement]] = {"probe": [], "expand": []}
     for n in sizes:
         lcs, rcs = _join_fixture(rng, n, n)
-        order, lo, counts = ops.hash_probe_numpy(lcs, rcs)
+        _, lo, counts = ops.hash_probe_numpy(lcs, rcs)
         total = int(counts.sum())
-        li, pos = ops.expand_pairs_numpy(lo, counts)
 
         k = timer(lambda: ops.hash_probe(lcs, rcs, use_kernel=True,
                                          interpret=interpret))
@@ -181,19 +178,11 @@ def tune_join(*, quick: bool = False,
                   if on_tpu else (lambda: ops.expand_pairs_numpy(lo, counts)))
         sweeps["expand"].append(Measurement("expand", total * n, k, f))
 
-        k = timer(lambda: ops.gather_rows(order, pos, use_kernel=True,
-                                          interpret=interpret,
-                                          bounded_by_len=True))
-        f = timer(lambda: order[pos])
-        sweeps["gather"].append(Measurement("gather", n, k, f))
-
     envelopes = {
         PROBE_CAP: crossover_cap(sweeps["probe"],
                                  default=_DEFAULTS[PROBE_CAP]),
         EXPAND_CAP: crossover_cap(sweeps["expand"],
                                   default=_DEFAULTS[EXPAND_CAP]),
-        GATHER_CAP: crossover_cap(sweeps["gather"],
-                                  default=_DEFAULTS[GATHER_CAP]),
     }
     return DispatchProfile(envelopes=envelopes,
                            backend=jax.default_backend(),

@@ -14,7 +14,7 @@ lives in one place:
 * :func:`resolve` — turn a caller's ``use_kernel``/``interpret`` pair
   (``None`` = auto) into concrete booleans.
 * :func:`envelope` / :func:`load_profile` — per-op scaling-envelope values
-  (the join family's probe-work / gather-residency / expand-work caps).
+  (the join family's probe-work / expand-work caps).
   Resolution order: process env var > a loaded **dispatch profile** >
   the op's hard-coded default. Profiles are recorded empirically by
   ``repro.kernels.autotune`` (kernel-vs-fallback crossover sweeps) and
@@ -130,8 +130,8 @@ def note_tier(op: str, tier: str, reason: str = "") -> None:
     registry (the owning ``KGService``'s): counters
     ``kernels.dispatch.<op>.<tier>`` and, when given, a companion
     ``...<tier>.<reason>`` — so tier picks (pallas/oracle/host) and their
-    fallback reasons (size floor, work caps, int32 envelopes, VMEM
-    residency) are attributable per op. No-op when no registry is
+    fallback reasons (size floor, work caps, int32 envelopes) are
+    attributable per op. No-op when no registry is
     installed; called once per op dispatch, never per row."""
     from repro.obs import metrics as obs_metrics
     m = obs_metrics.ambient()

@@ -18,8 +18,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -125,7 +123,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, q_offset: int = 0,
             pltpu.VMEM((bq,), jnp.float32),        # running max
             pltpu.VMEM((bq,), jnp.float32),        # running sum
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

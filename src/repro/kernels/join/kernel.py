@@ -1,5 +1,5 @@
 """Pallas TPU kernels: hash-join key packing, sorted probe, segmented
-ragged expansion, masked gather.
+ragged expansion.
 
 The executor's hash join has four vectorizable stages:
 
@@ -20,7 +20,9 @@ The executor's hash join has four vectorizable stages:
    gathers on the VPU. Zero-count segments own nothing and drop out for
    free, which also makes the padding inert.
 4. **gather** — index the build side's sort permutation with the expanded
-   match positions.
+   match positions. This stage has no kernel here: Mosaic lowers only 2-D
+   gathers, and a gather over a (1, N) lane row is refused by the TPU
+   compiler, so the ops layer runs XLA's device gather instead.
 
 TPUs have no int64, so packed keys travel through the kernels as two 32-bit
 words: ``hi = key >> 32`` (int32, < 2^30 for K <= 2) and ``lo = key &
@@ -35,7 +37,7 @@ On a sorted build side those counts *are* the searchsorted indices. The
 build-side sort itself stays on the host (``np.argsort``), exactly like the
 executor's jitted-jnp path.
 
-Grids: pack/gather are 1-D over row tiles; probe is (N/BN, M/BM) with the
+Grids: pack is 1-D over row tiles; probe is (N/BN, M/BM) with the
 output accumulated over the build axis (TPU grids iterate sequentially, so
 read-modify-write on the j axis is the standard reduction pattern). All
 arrays are carried as (1, N) lane-major panels to respect the 128-lane
@@ -216,41 +218,3 @@ def expand_pairs_pallas(starts: jnp.ndarray, counts: jnp.ndarray,
     )(st, ct, lp)
     return li[0, :total], pos[0, :total]
 
-
-# --------------------------------------------------------------------------- #
-# gather
-# --------------------------------------------------------------------------- #
-
-def _gather_kernel(val_ref, idx_ref, out_ref, *, n_values: int, fill: int):
-    vals = val_ref[0, :]                              # full table, resident
-    idx = idx_ref[0, :]
-    safe = jnp.clip(idx, 0, max(n_values - 1, 0))
-    out = jnp.take(vals, safe, axis=0)
-    out_ref[0, :] = jnp.where((idx >= 0) & (idx < n_values), out,
-                              jnp.asarray(fill, vals.dtype))
-
-
-@functools.partial(jax.jit, static_argnames=("fill", "block_n", "interpret"))
-def gather_rows_pallas(values: jnp.ndarray, idx: jnp.ndarray, *,
-                       fill: int = 0, block_n: int = 1024,
-                       interpret: bool = False) -> jnp.ndarray:
-    """Masked gather ``values[idx]`` (int32), out-of-range -> ``fill``.
-
-    The value table stays resident across the row-tile grid (one VMEM
-    panel), each program gathers one tile of indices against it.
-    """
-    m, n = values.shape[0], idx.shape[0]
-    mp = max(128, (m + 127) // 128 * 128)
-    np_ = max(block_n, (n + block_n - 1) // block_n * block_n)
-    vals = _pad_to(values.astype(jnp.int32)[None, :], mp, 0)
-    idxp = _pad_to(idx.astype(jnp.int32)[None, :], np_, -1)
-    out = pl.pallas_call(
-        functools.partial(_gather_kernel, n_values=m, fill=fill),
-        grid=(np_ // block_n,),
-        in_specs=[pl.BlockSpec((1, mp), lambda i: (0, 0)),
-                  pl.BlockSpec((1, block_n), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
-        interpret=interpret,
-    )(vals, idxp)
-    return out[0, :n]

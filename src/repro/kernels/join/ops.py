@@ -12,7 +12,7 @@ Three layers, same math (see ``docs/kernels.md`` for the idiom):
 * this module — the dispatch seam the executor calls. The join sits on the
   per-query serving hot path, so the auto policy is ``hot_path=True``
   (``repro.kernels.dispatch``) plus two scaling guards (the quadratic
-  probe-work cap and the gather VMEM-residency cap below): compiled
+  probe-work and expand-work caps below): compiled
   kernels on TPU for large-enough in-envelope problems, the jitted oracle
   for the rest of the device cases, and plain host numpy
   (:func:`hash_probe_numpy`) when there is no device at all;
@@ -46,6 +46,7 @@ import contextlib
 import dataclasses
 from typing import List, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.kernels import dispatch
@@ -62,18 +63,12 @@ _oracle_cache: dict = {}
 # * the count-probe kernel does O(nl * nr) word-pair compares — a win over
 #   binary search only while the compare budget is small; past the cap the
 #   log-depth oracle is asymptotically faster even with its device hops.
-# * the gather kernel keeps the whole value table resident in one VMEM
-#   panel; past ~2M int32 rows (8 MB of the ~16 MB VMEM) it cannot tile.
 # * the expand kernel broadcast-tests O(total * n_segments) ownership
 #   pairs (the expansion-total threshold): past the cap the log-depth
 #   searchsorted oracle wins, exactly like the probe.
 
 def _probe_work_cap() -> int:
     return dispatch.envelope("REPRO_JOIN_PROBE_WORK_CAP", 1 << 32)
-
-
-def _gather_resident_rows() -> int:
-    return dispatch.envelope("REPRO_JOIN_GATHER_RESIDENT_ROWS", 1 << 21)
 
 
 def _expand_work_cap() -> int:
@@ -175,10 +170,13 @@ def _pipe_fns():
             sub=jax.jit(lambda a, b: a - b),
             clamp=jax.jit(lambda x, n: jnp.minimum(x, n)),
             total64=jax.jit(lambda c: jnp.sum(c.astype(jnp.int64))),
+            # a device scan: about a minute to compile for the TPU at ~1M
+            # rows, so the kernel pipeline takes its prefix sums from the host
             starts=jax.jit(lambda c: jnp.cumsum(c) - c),
             join_words=jax.jit(lambda hi, lo: (hi.astype(jnp.int64) << 32)
                                | lo.astype(jnp.uint32).astype(jnp.int64)),
             expand=jax.jit(ref.expand_pairs, static_argnames=("total",)),
+            gather=jax.jit(ref.gather_rows, static_argnames=("fill",)),
             pad_to=pad_to,
         )
     return _pipe_cache
@@ -216,8 +214,7 @@ def pack_keys(cols: np.ndarray, *, use_kernel: bool | None = None,
     if not use_kernel:
         dispatch.note_tier("join.pack_keys", "oracle",
                            "auto" if auto else "forced_off")
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             pack, _ = _oracle_fns()
             _note(h2d=1, d2h=1)
             return np.asarray(pack(cols.astype(np.int64)))
@@ -249,8 +246,7 @@ def probe_sorted(build_sorted: np.ndarray, probe: np.ndarray, *,
         dispatch.note_tier("join.probe_sorted", "oracle",
                            "work_cap" if capped
                            else "auto" if auto else "forced_off")
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             _, search = _oracle_fns()
             _note(h2d=2, d2h=2)
             lo, hi = search(build_sorted, probe)
@@ -265,47 +261,27 @@ def probe_sorted(build_sorted: np.ndarray, probe: np.ndarray, *,
 
 
 def gather_rows(values: np.ndarray, idx: np.ndarray, *, fill: int = 0,
-                use_kernel: bool | None = None,
-                interpret: bool | None = None,
-                assume_inbounds: bool = False,
-                bounded_by_len: bool = False) -> np.ndarray:
-    """Masked gather ``values[idx]`` (out-of-range -> ``fill``); the host
-    gather is its own oracle — a one-op kernel needs no jnp round trip.
+                on_device: bool | None = None,
+                assume_inbounds: bool = False) -> np.ndarray:
+    """Masked gather ``values[idx]`` (out-of-range -> ``fill``). Two tiers
+    and no Pallas kernel: Mosaic lowers only 2-D gathers, so a gather over
+    a (1, N) lane row cannot compile for the TPU, and XLA's device gather
+    already does the job. ``on_device=None`` (auto) runs the device gather
+    on TPU and host numpy elsewhere; ``True``/``False`` pin a tier. The
+    device tier runs under x64, so int64 tables gather exactly.
 
     ``assume_inbounds=True`` lets a caller that guarantees valid indices
     (the executor's expansion positions are constructed in range) skip the
-    host tier's masking passes; the kernel tier masks either way (the mask
-    is inert for valid indices).
-
-    ``bounded_by_len=True`` declares every value nonnegative and bounded by
-    ``len(values)`` — true of permutation tables like a build-side sort
-    order — so the int32-envelope check on the kernel tier is the O(1)
-    proof ``len(values) <= 2^31`` instead of a min/max scan over the whole
-    int64 table (two host passes per join on the TPU path)."""
+    host tier's masking passes; the device tier masks either way (the mask
+    is inert for valid indices)."""
     values = np.asarray(values)
     idx = np.asarray(idx)
-    auto = use_kernel is None
-    use_kernel, interpret = dispatch.resolve(use_kernel, interpret,
-                                             idx.shape[0], hot_path=True)
-    fallback_reason = "auto" if auto else "forced_off"
-    if use_kernel and auto and values.shape[0] > _gather_resident_rows():
-        use_kernel = False             # table would not fit one VMEM panel
-        fallback_reason = "vmem_residency"
-    if use_kernel and values.size:
-        # the kernel carries values as int32 words; out-of-envelope tables
-        # would silently truncate, so auto falls back and forced raises.
-        # A length-bounded table (e.g. a sort permutation: values are
-        # indices into itself) is proven in-envelope in O(1).
-        in_envelope = (values.shape[0] <= (1 << 31) if bounded_by_len
-                       else (values.min() >= -(1 << 31)
-                             and values.max() < 1 << 31))
-        if not in_envelope:
-            if not auto:
-                raise ValueError("gather kernel requires int32-range values")
-            use_kernel = False
-            fallback_reason = "int32_envelope"
-    if not use_kernel:
-        dispatch.note_tier("join.gather_rows", "host", fallback_reason)
+    auto = on_device is None
+    if auto:
+        on_device = dispatch.on_tpu()
+    if not on_device:
+        dispatch.note_tier("join.gather_rows", "host",
+                           "cpu_auto" if auto else "forced_off")
         if assume_inbounds:
             return values[idx]
         valid = (idx >= 0) & (idx < len(values))
@@ -314,13 +290,11 @@ def gather_rows(values: np.ndarray, idx: np.ndarray, *, fill: int = 0,
         if len(values):
             out[valid] = values[np.clip(idx, 0, len(values) - 1)][valid]
         return out
-    dispatch.note_tier("join.gather_rows", "pallas",
+    dispatch.note_tier("join.gather_rows", "xla",
                        "auto" if auto else "forced")
-    got = kernel.gather_rows_pallas(values.astype(np.int32),
-                                    idx.astype(np.int32), fill=fill,
-                                    interpret=interpret)
-    _note(h2d=2, d2h=1)
-    return np.asarray(got).astype(values.dtype if values.size else np.int32)
+    with jax.enable_x64(True):
+        _note(h2d=2, d2h=1)
+        return np.asarray(_pipe_fns()["gather"](values, idx, fill=fill))
 
 
 # --------------------------------------------------------------------------- #
@@ -356,10 +330,8 @@ def hash_probe_oracle(lcs: Sequence[np.ndarray], rcs: Sequence[np.ndarray],
     pow2-padded pack + searchsorted under ``enable_x64``, host build sort.
     Padding keys are int64-max so they never binary-search below a real
     key; results are clamped back to the true build size."""
-    from jax.experimental import enable_x64
-
     nl, nr = len(lcs[0]), len(rcs[0])
-    with enable_x64():
+    with jax.enable_x64(True):
         pack, search = _oracle_fns()
         _note(h2d=2, d2h=2)
         lk = np.asarray(pack(_pad_pow2(np.stack(lcs, axis=1))))[:nl]
@@ -444,13 +416,12 @@ def _expand_pairs_oracle(lo: np.ndarray, counts: np.ndarray, total: int,
     buckets. Zero-fill padding segments own no output index, and padded
     output indices past ``total`` resolve to the last padding segment —
     both sliced off on the way out."""
-    from jax.experimental import enable_x64
-
-    n = counts.shape[0]
-    with enable_x64():
+    starts = np.cumsum(counts) - counts
+    with jax.enable_x64(True):
         fns = _pipe_fns()
-        _note(h2d=2, d2h=2)
-        li, pos = fns["expand"](_pad_pow2(lo), _pad_pow2(counts),
+        _note(h2d=3, d2h=2)
+        li, pos = fns["expand"](_pad_pow2(starts, fill=total),
+                                _pad_pow2(counts), _pad_pow2(lo),
                                 total=_pow2_len(total))
         return (np.asarray(li)[:total].astype(np.int64),
                 np.asarray(pos)[:total].astype(np.int64))
@@ -554,12 +525,10 @@ def _pipeline_oracle(lcs, rcs, max_total):
     on the host by design), the expansion-total scalar down, and the final
     ``(li, ri)`` pair down — 7, vs the staged oracle composite's 12 plus
     its full intermediate arrays."""
-    from jax.experimental import enable_x64
-
     import jax.numpy as jnp
 
     nl, nr = len(lcs[0]), len(rcs[0])
-    with enable_x64():
+    with jax.enable_x64(True):
         pack, search = _oracle_fns()
         fns = _pipe_fns()
         _note(h2d=2)
@@ -581,8 +550,9 @@ def _pipeline_oracle(lcs, rcs, max_total):
         if total == 0:
             return _EMPTY_PAIR
         mp = _pow2_len(nl)
-        li_d, pos_d = fns["expand"](fns["pad_to"](lo_d, n=mp, fill=0),
-                                    fns["pad_to"](counts_d, n=mp, fill=0),
+        counts_p = fns["pad_to"](counts_d, n=mp, fill=0)
+        li_d, pos_d = fns["expand"](fns["starts"](counts_p), counts_p,
+                                    fns["pad_to"](lo_d, n=mp, fill=0),
                                     total=_pow2_len(total))
         ri_d = fns["take"](order_d, pos_d[:total])
         _note(d2h=2)
@@ -591,15 +561,16 @@ def _pipeline_oracle(lcs, rcs, max_total):
 
 
 def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
-    """Kernel pipeline: pack/probe/expand/gather as Pallas kernels with
-    device-resident word-pair intermediates; per-stage scaling-envelope
-    fallbacks swap in the jitted jnp form of that one stage *on device*
-    instead of dropping the whole join to the host. Boundary crossings:
-    two key-column uploads, the recombined sort key down + the order back
-    up, the total scalar down, the final pair down — 7, vs the staged
-    all-kernel composite's 20."""
-    from jax.experimental import enable_x64
-
+    """Kernel pipeline: pack/probe/expand as Pallas kernels and the gather
+    as XLA's device gather, with device-resident word-pair intermediates;
+    per-stage scaling-envelope fallbacks swap in the jitted jnp form of
+    that one stage *on device* instead of dropping the whole join to the
+    host. Boundary crossings: two key-column uploads, the recombined sort
+    key down + the order back up, the match counts down + their exclusive
+    prefix sum back up, the final pair down — 8, vs the staged all-kernel
+    composite's 20. The prefix sum runs on the host because the TPU
+    compiler takes about a minute over a device scan of ~1M rows, and the
+    join sizes change with every write."""
     import jax.numpy as jnp
 
     nl, nr = len(lcs[0]), len(rcs[0])
@@ -623,7 +594,7 @@ def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
         np.stack(rcs, axis=1).astype(np.int32), interpret=interpret)
     # build-side sort on the host by design: the recombined int64 key is
     # the one mid-pipeline materialization, the order the one extra upload
-    with enable_x64():
+    with jax.enable_x64(True):
         rk_d = fns["join_words"](rh, rl)
     _note(d2h=1)
     order = np.argsort(np.asarray(rk_d), kind="stable")
@@ -634,7 +605,7 @@ def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
     if auto and nl * nr > _probe_work_cap():
         # compare budget exceeded: this stage runs as the device oracle
         dispatch.note_tier("join.pipeline.probe", "oracle", "work_cap")
-        with enable_x64():
+        with jax.enable_x64(True):
             _, search = _oracle_fns()
             lo_j, hi_j = search(rk_d[order_d],
                                 fns["join_words"](lh, ll))
@@ -644,9 +615,9 @@ def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
         lo_d, hi_d = kernel.probe_sorted_pallas(rh_s, rl_s, lh, ll,
                                                 interpret=interpret)
         counts_d = fns["sub"](hi_d, lo_d)
-    with enable_x64():
-        _note(d2h=1)
-        total = int(fns["total64"](counts_d))
+    _note(d2h=1)
+    counts = np.asarray(counts_d).astype(np.int64)
+    total = int(counts.sum())
     _check_total(total, max_total)
     if total == 0:
         return _EMPTY_PAIR
@@ -654,31 +625,28 @@ def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
         # past the int32 envelope no device stage can carry the expansion;
         # finish on the host (auto would normally cap out long before this)
         dispatch.note_tier("join.pipeline.expand", "host", "int32_envelope")
-        lo_h = np.asarray(lo_d).astype(np.int64)
-        ct_h = np.asarray(counts_d).astype(np.int64)
-        li, pos = expand_pairs_numpy(lo_h, ct_h)
+        li, pos = expand_pairs_numpy(np.asarray(lo_d).astype(np.int64),
+                                     counts)
         return li, order[pos].astype(np.int64), total
+    _note(h2d=1)
+    starts_d = jnp.asarray((np.cumsum(counts) - counts).astype(np.int32))
     tp = _pow2_len(total)
     if auto and total * nl > _expand_work_cap():
         # ownership-test budget exceeded: searchsorted oracle, on device
         dispatch.note_tier("join.pipeline.expand", "oracle", "work_cap")
         mp = _pow2_len(nl)
-        li_d, pos_d = fns["expand"](fns["pad_to"](lo_d, n=mp, fill=0),
+        li_d, pos_d = fns["expand"](fns["pad_to"](starts_d, n=mp, fill=total),
                                     fns["pad_to"](counts_d, n=mp, fill=0),
+                                    fns["pad_to"](lo_d, n=mp, fill=0),
                                     total=tp)
-        li_d, pos_d = li_d[:total], pos_d[:total]
     else:
-        starts_d = fns["starts"](counts_d)
         li_d, pos_d = kernel.expand_pairs_pallas(starts_d, counts_d, lo_d,
                                                  total=tp,
                                                  interpret=interpret)
-        li_d, pos_d = li_d[:total], pos_d[:total]
-    if auto and nr > _gather_resident_rows():
-        dispatch.note_tier("join.pipeline.gather", "oracle",
-                           "vmem_residency")
-        ri_d = fns["take"](order_d, pos_d)     # table too big for one panel
-    else:
-        ri_d = kernel.gather_rows_pallas(order_d, pos_d, interpret=interpret)
+    li_d, pos_d = li_d[:total], pos_d[:total]
+    # XLA's device gather: Mosaic has no lowering for a 1-D lane gather
+    dispatch.note_tier("join.pipeline.gather", "xla")
+    ri_d = fns["take"](order_d, pos_d)
     _note(d2h=2)
     return (np.asarray(li_d).astype(np.int64),
             np.asarray(ri_d).astype(np.int64), total)

@@ -3,7 +3,7 @@
 This is the same math the executor's pre-Pallas jitted path runs (and the
 numpy reference backend, modulo device): packed int64 keys, binary-search
 probe against the sorted build side, plain gather. int64 keys require
-``jax.experimental.enable_x64`` on the caller's side (the ops layer handles
+``jax.enable_x64(True)`` on the caller's side (the ops layer handles
 it); two dictionary ids (< 2^31) pack exactly into one int64.
 """
 from __future__ import annotations
@@ -30,19 +30,21 @@ def probe_sorted(build_sorted: jnp.ndarray, probe: jnp.ndarray,
     return lo, hi
 
 
-def expand_pairs(lo: jnp.ndarray, counts: jnp.ndarray, total: int,
+def expand_pairs(starts: jnp.ndarray, counts: jnp.ndarray,
+                 lo: jnp.ndarray, total: int,
                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Segmented ragged expansion of ``(lo, counts)`` match runs into flat
     ``(li, pos)`` pair indices — the jnp form of the executor's former
-    ``np.repeat``/``np.cumsum`` addressing arithmetic.
+    ``np.repeat``/``np.cumsum`` addressing arithmetic, with the kernel's
+    signature.
 
-    ``starts`` (exclusive cumsum) partitions ``[0, counts.sum())`` into
-    runs; output ``j``'s owner is the *last* segment whose start is ``<=
-    j`` (``searchsorted`` right minus one — duplicate starts from
-    zero-count segments resolve to the one segment that actually owns
-    ``j``). ``total`` is static for jit; indices past ``counts.sum()``
-    resolve to the last segment and must be sliced off by the caller."""
-    starts = jnp.cumsum(counts) - counts
+    ``starts`` (the exclusive cumsum of ``counts``, computed by the caller)
+    partitions ``[0, counts.sum())`` into runs; output ``j``'s owner is the
+    *last* segment whose start is ``<= j`` (``searchsorted`` right minus one
+    — duplicate starts from zero-count segments resolve to the one segment
+    that actually owns ``j``). ``total`` is static for jit; indices past
+    ``counts.sum()`` resolve to the last segment and must be sliced off by
+    the caller."""
     j = jnp.arange(total, dtype=counts.dtype)
     seg = jnp.searchsorted(starts, j, side="right") - 1
     pos = lo[seg] + j - starts[seg]

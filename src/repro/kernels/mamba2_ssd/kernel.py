@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, s0_ref,
                 y_ref, sout_ref, state_ref, *, chunk: int, n_chunks: int):
@@ -105,7 +103,7 @@ def ssd_pallas(x, b, c, dt, a, d, s0, *, chunk: int = 128,
         out_shape=[jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
                    jax.ShapeDtypeStruct((bh, n, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, hd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, b.astype(jnp.float32), c.astype(jnp.float32), dtf, af, df, s0f)

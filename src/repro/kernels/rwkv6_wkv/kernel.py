@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
                 state_ref, *, chunk: int, n_chunks: int):
@@ -103,7 +101,7 @@ def wkv_pallas(r, k, v, w, u, s0, *, chunk: int = 64,
         out_shape=[jax.ShapeDtypeStruct((bh, s, hd), jnp.float32),
                    jax.ShapeDtypeStruct((bh, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0f)

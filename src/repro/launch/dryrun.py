@@ -24,7 +24,6 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro import compat
 import repro.configs as configs
 from repro.configs.base import SHAPES, ArchConfig, shape_supported
 from repro.launch import hlo_analysis, roofline, sharding
@@ -68,13 +67,13 @@ def lower_cell(cfg: ArchConfig, shape_name: str, mesh, *,
         jitted = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                          out_shardings=(p_sh, o_sh, None),
                          donate_argnums=(0, 1) if donate else ())
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_sds, opt_sds, batch_sds)
     elif kind == "prefill":
         def step(params, batch):
             return lm.prefill_step(params, batch, cfg, ctx)
         jitted = jax.jit(step, in_shardings=(p_sh, b_sh))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_sds, batch_sds)
     else:  # decode
         cache_sds = lm.cache_specs(cfg, shape_name)
@@ -89,7 +88,7 @@ def lower_cell(cfg: ArchConfig, shape_name: str, mesh, *,
         jitted = jax.jit(step, in_shardings=(p_sh, c_sh, b_sh),
                          out_shardings=(None, c_sh),
                          donate_argnums=(1,) if donate else ())
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(params_sds, cache_sds, batch_sds)
 
     compiled = lowered.compile()
@@ -114,7 +113,7 @@ def extrapolated_costs(cfg: ArchConfig, shape_name: str, mesh) -> dict:
             over["attn_every"] = attn_every
         cfg_s = dataclasses.replace(cfg, **over)
         _, compiled = lower_cell(cfg_s, shape_name, mesh, donate=False)
-        cost = compat.cost_analysis(compiled)
+        cost = dict(compiled.cost_analysis())
         coll = hlo_analysis.collective_bytes(compiled.as_text())
         return dict(flops=float(cost.get("flops", 0.0)),
                     bytes=float(cost.get("bytes accessed", 0.0)),
@@ -161,7 +160,7 @@ def analyze_cell(cfg: ArchConfig, shape_name: str, mesh_name: str,
     n_tokens = (info["global_batch"] * info["seq_len"]
                 if kind in ("train", "prefill") else info["global_batch"])
 
-    cost = compat.cost_analysis(compiled)
+    cost = dict(compiled.cost_analysis())
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = hlo_analysis.collective_bytes(hlo)

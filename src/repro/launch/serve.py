@@ -33,7 +33,9 @@ dumps the metrics-registry snapshot.
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 from typing import Dict
 
 import numpy as np
@@ -46,6 +48,24 @@ from repro.query import rewrite
 
 PARTITIONERS = {"hash": HashPartitioner, "wawpart": WawPartitioner,
                 "awapart": AWAPartitioner}
+
+# the checkout's own compile-cache directory (listed in .gitignore)
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def setup_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed directory: the
+    one ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+    ``.jax_cache/`` at the root of this checkout. A cache is found again
+    only at the path it was written to, so the path never depends on a
+    temporary name, a PID or the time. Entry points call this from
+    ``main``; importing a module never touches the cache."""
+    import jax
+
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(CHECKOUT_CACHE_DIR))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_system(universities: int, shards: int, seed: int = 0,
@@ -280,6 +300,7 @@ def main() -> None:
                     help="dump the service's metrics-registry snapshot "
                          "(counters/gauges/histograms) as CSV to PATH")
     args = ap.parse_args()
+    setup_compile_cache()
 
     t0 = time.time()
     ds, svc = build_system(args.universities, args.shards,

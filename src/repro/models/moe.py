@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models.layers import Axes, Params, _dtype, dense_init
 
@@ -278,7 +277,7 @@ def moe_apply(p: Params, x: jnp.ndarray, cfg: ArchConfig,
     # replicated over the tp axis; every rank reconstructs the identical
     # combined output after the return all_to_all, which the static
     # replication checker cannot infer.
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(w_spec, x_spec),
         out_specs=(x_spec, P()),

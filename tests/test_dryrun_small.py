@@ -14,7 +14,6 @@ _SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, jax
-from repro import compat
 import repro.configs as configs
 from repro.launch import hlo_analysis, sharding
 from repro.launch.mesh import dp_axes, make_host_mesh
@@ -46,11 +45,11 @@ for arch in ("smollm-360m", "olmoe-1b-7b", "rwkv6-3b", "zamba2-7b"):
     def step(params, opt_state, batch, cfg=cfg, ctx=ctx):
         return lm.train_step(params, opt_state, batch, cfg, ctx, opt_cfg)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh)).lower(
             params_sds, opt_sds, batch_sds)
         compiled = lowered.compile()
-    cost = compat.cost_analysis(compiled)
+    cost = dict(compiled.cost_analysis())
     coll = hlo_analysis.collective_bytes(compiled.as_text())
     assert cost.get("flops", 0) > 0, arch
     assert coll["total_count"] > 0, arch    # DP grads must sync

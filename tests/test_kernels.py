@@ -204,12 +204,14 @@ def test_join_probe_sorted_duplicates_and_misses(rng):
     assert ((got[1] - got[0])[len(build[::5]):] == 0).all()
 
 
-def test_join_gather_rows_masks_out_of_range(rng):
+@pytest.mark.parametrize("on_device", [True, None])
+def test_join_gather_rows_masks_out_of_range(rng, on_device):
+    """The device XLA gather and auto dispatch mask out-of-range indices
+    exactly like the host tier."""
     vals = rng.integers(0, 10_000, 97)
     idx = np.array([-5, -1, 0, 50, 96, 97, 10_000])
-    ref = jops.gather_rows(vals, idx, fill=-3, use_kernel=False)
-    got = jops.gather_rows(vals, idx, fill=-3, use_kernel=True,
-                           interpret=True)
+    ref = jops.gather_rows(vals, idx, fill=-3, on_device=False)
+    got = jops.gather_rows(vals, idx, fill=-3, on_device=on_device)
     np.testing.assert_array_equal(ref, got)
     assert (ref[[0, 1, 5, 6]] == -3).all()
 
@@ -261,8 +263,9 @@ def test_join_probe_tiers_agree(rng):
 
 def test_join_auto_guards_respect_scaling_envelopes(rng, monkeypatch):
     """Auto dispatch falls back past the kernels' scaling envelopes (the
-    O(nl*nr) probe compare budget, the gather VMEM-residency cap) while
-    forced use_kernel=True still pins the kernel path; results agree."""
+    O(nl*nr) probe compare budget) while forced use_kernel=True still pins
+    the kernel path; on a TPU the gather serves its device tier. Results
+    agree."""
     from repro.kernels.join import ops as live_ops
 
     lcs = [rng.integers(0, MAXID, 40).astype(np.int64)]
@@ -270,7 +273,6 @@ def test_join_auto_guards_respect_scaling_envelopes(rng, monkeypatch):
     rcs[0][:20] = lcs[0][:20]
     monkeypatch.setenv("REPRO_KERNEL_THRESHOLD", "10")        # over the floor
     monkeypatch.setenv("REPRO_JOIN_PROBE_WORK_CAP", "100")    # 40*50 > 100
-    monkeypatch.setenv("REPRO_JOIN_GATHER_RESIDENT_ROWS", "8")
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)     # auto -> kernel
     # without the guards these autos would now try to compile the kernels
     # for a backend that doesn't exist — the fallbacks must engage first
@@ -295,16 +297,15 @@ def test_join_gather_assume_inbounds_matches_masked(rng):
     idx = rng.integers(0, 64, 200)
     a = jops.gather_rows(vals, idx)
     b = jops.gather_rows(vals, idx, assume_inbounds=True)
-    c = jops.gather_rows(vals, idx, use_kernel=True, interpret=True,
-                         assume_inbounds=True)
+    c = jops.gather_rows(vals, idx, on_device=True, assume_inbounds=True)
     np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(a, c)
 
 
 def test_join_kernel_contract_guards(rng):
     """Public-op contract enforcement: the word-pair kernels reject packed
-    keys past the 2^62 envelope, and the gather kernel refuses (forced) or
-    avoids (auto) tables whose values would truncate through int32."""
+    keys past the 2^62 envelope, and every gather tier keeps tables whose
+    values pass int32 exact (the device gather runs under x64)."""
     big = np.array([1 << 62], np.int64)
     ok = np.array([5, (1 << 62) - 1], np.int64)
     with pytest.raises(ValueError, match="2\\^62"):
@@ -315,13 +316,13 @@ def test_join_kernel_contract_guards(rng):
 
     wide = np.array([1 << 40, 7], np.int64)
     idx = np.array([0, 1])
-    with pytest.raises(ValueError, match="int32"):
-        jops.gather_rows(wide, idx, use_kernel=True, interpret=True)
-    # auto dispatch silently serves the host tier instead of truncating
-    np.testing.assert_array_equal(jops.gather_rows(wide, idx), wide)
-    # kernel-tier output keeps the table's dtype
+    for on_device in (None, False, True):
+        got = jops.gather_rows(wide, idx, on_device=on_device)
+        assert got.dtype == np.int64, on_device
+        np.testing.assert_array_equal(got, wide, err_msg=str(on_device))
+    # device-tier output keeps the table's dtype
     small = rng.integers(0, 100, 16).astype(np.int16)
-    got = jops.gather_rows(small, idx, use_kernel=True, interpret=True)
+    got = jops.gather_rows(small, idx, on_device=True)
     assert got.dtype == small.dtype
     np.testing.assert_array_equal(got, small[idx])
 
@@ -455,8 +456,7 @@ def test_join_pipeline_transfers_strictly_below_staged(rng):
     def staged(probe_fn, gather_kw):
         order, lo, counts = probe_fn()
         li, pos = jops.expand_pairs_numpy(lo, counts)
-        jops.gather_rows(order, pos, assume_inbounds=True,
-                         bounded_by_len=True, **gather_kw)
+        jops.gather_rows(order, pos, assume_inbounds=True, **gather_kw)
 
     for label, fused_kw, probe_fn, gather_kw in (
             ("oracle", {"mode": "oracle"},
@@ -465,7 +465,7 @@ def test_join_pipeline_transfers_strictly_below_staged(rng):
                         "interpret": True},
              lambda: jops.hash_probe(lcs, rcs, use_kernel=True,
                                      interpret=True),
-             {"use_kernel": True, "interpret": True})):
+             {"on_device": True})):
         with jops.track_transfers() as fused:
             jops.hash_join_pipeline(lcs, rcs, **fused_kw)
         with jops.track_transfers() as stag:
@@ -491,7 +491,7 @@ def test_join_pipeline_cap_fires_before_materialization(rng):
 
 
 def test_join_pipeline_per_stage_envelope_fallbacks(rng, monkeypatch):
-    """Past the probe/expand/gather envelopes the pallas pipeline swaps
+    """Past the probe/expand envelopes the pallas pipeline swaps
     single stages for their device oracles (never the whole join to host):
     results stay bit-identical and no kernel compile for a fake TPU is
     attempted."""
@@ -499,7 +499,6 @@ def test_join_pipeline_per_stage_envelope_fallbacks(rng, monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_THRESHOLD", "10")
     monkeypatch.setenv("REPRO_JOIN_PROBE_WORK_CAP", "100")
     monkeypatch.setenv("REPRO_JOIN_EXPAND_WORK_CAP", "100")
-    monkeypatch.setenv("REPRO_JOIN_GATHER_RESIDENT_ROWS", "8")
     monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
     # interpret pinned: the un-guarded pack stage still runs its kernel,
     # which must not try to compile for the faked TPU on this CPU host
@@ -545,13 +544,12 @@ def test_autotune_tune_join_with_synthetic_timer():
     prof = autotune.tune_join(quick=True,
                               timer=lambda fn: next(slow_kernel))
     assert all(v == 0 for v in prof.envelopes.values())
-    assert {m.stage for m in prof.measurements} == {"probe", "expand",
-                                                    "gather"}
+    assert {m.stage for m in prof.measurements} == {"probe", "expand"}
     fast_kernel = iter([1.0, 5.0] * 100)
     prof = autotune.tune_join(quick=True,
                               timer=lambda fn: next(fast_kernel))
     assert prof.envelopes[autotune.PROBE_CAP] == 1 << 32
-    assert prof.envelopes[autotune.GATHER_CAP] == 1 << 21
+    assert prof.envelopes[autotune.EXPAND_CAP] == 1 << 32
 
 
 def test_autotune_profile_roundtrip_and_resolution_order(tmp_path,
@@ -578,7 +576,8 @@ def test_autotune_profile_roundtrip_and_resolution_order(tmp_path,
         back.install()
         assert live_ops._probe_work_cap() == 123
         assert live_ops._expand_work_cap() == 456
-        assert live_ops._gather_resident_rows() == 1 << 21   # not recorded
+        assert (dispatch.kernel_threshold()                  # not recorded
+                == dispatch.DEFAULT_KERNEL_THRESHOLD)
         monkeypatch.setenv(autotune.PROBE_CAP, "77")
         assert live_ops._probe_work_cap() == 77
         assert live_ops._expand_work_cap() == 456            # env only wins

@@ -94,7 +94,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import moe
 from repro.core import placement
@@ -106,9 +105,10 @@ cfg = ArchConfig(arch_id="t", family="moe", n_layers=1, d_model=32,
 p, _ = moe.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
 y_dense, _ = moe.moe_apply_dense(p, x, cfg)
-mesh = compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = moe.ShardCtx(mesh=mesh, dp_axes=("data",))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_e, _ = moe.moe_apply(p, x, cfg, ctx)
     y_r, _ = moe.moe_apply(p, x,
                            dataclasses.replace(cfg, moe_dispatch="rank"), ctx)
@@ -118,7 +118,7 @@ assert float(jnp.abs(y_r - y_dense).max()) < 1e-5, "rank dispatch"
 rng = np.random.default_rng(0)
 e2r = placement.plan_expert_placement(rng.integers(0, 8, (64, 2)), 8, 4)[0]
 p2 = placement.apply_expert_placement(p, e2r)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_e2, _ = moe.moe_apply(p2, x, cfg, ctx)
     y_r2, _ = moe.moe_apply(p2, x,
                             dataclasses.replace(cfg, moe_dispatch="rank"), ctx)
