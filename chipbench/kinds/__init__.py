@@ -1,0 +1,2 @@
+"""Serving loops, one module per kind of traffic, found by the traffic
+file's ``kind``."""
