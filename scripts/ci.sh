@@ -342,14 +342,18 @@ for ev in events:                 # Chrome trace-event schema (Perfetto)
     if ev["ph"] == "X":
         assert ev["dur"] >= 0 and ev["ts"] >= 0, ev
 names = [ev["name"] for ev in events if ev["ph"] == "X"]
-for needed in ("adapt.round", "migration.chunk", "window", "query",
-               "plan", "scan", "join", "federate", "ship"):
+for needed in ("repro.adapt.round", "repro.migrate.chunk",
+               "repro.serve.window", "repro.serve.plan", "repro.exec.query",
+               "repro.exec.scan", "repro.exec.join",
+               "repro.exec.federation"):
     assert needed in names, f"missing {needed} spans in the trace"
-n_rounds = names.count("adapt.round")
+assert all({"seq", "parent", "req"} <= set(ev["args"])
+           for ev in events if ev["ph"] == "X"), "span without its parent"
+n_rounds = names.count("repro.adapt.round")
 assert n_rounds >= 1, "no adaptation-round span recorded"
 print(f"[ci] trace schema ok: {len(events)} events, {n_rounds} adaptation "
-      f"round(s), {names.count('migration.chunk')} migration chunks, "
-      f"{names.count('query')} query spans")
+      f"round(s), {names.count('repro.migrate.chunk')} migration chunks, "
+      f"{names.count('repro.exec.query')} executed queries")
 EOF
 python results/make_table.py /tmp/ci_metrics.csv
 python results/make_table.py /tmp/ci_metrics.csv --md > /dev/null

@@ -27,11 +27,14 @@ Everything callers need to serve a partitioned knowledge graph:
   ``repro.kernels.join`` Pallas kernel family), re-exported from
   ``repro.query.exec``;
 * observability: :class:`Tracer` / :class:`MetricsRegistry`
-  (``repro.obs``) — ``KGService(trace=True)`` records per-query
-  plan→scan→join→federate→ship spans plus window / migration-chunk /
-  write-batch / adaptation-round spans on the modeled clock
+  (``repro.obs``) — the program's spans measure real time where the work
+  happens (serving window, planning, scans, joins and their stages,
+  federation, adaptation round and its phases, migration chunks, writes)
+  and show on a ``jax.profiler`` capture; ``KGService(trace=True)`` also
+  keeps them, with parent and request id, on the wall clock
   (``svc.tracer().export("out.json")`` is Perfetto-loadable), and every
-  service folds its metrics snapshot into ``stats()["metrics"]``.
+  service folds its metrics snapshot, span totals included, into
+  ``stats()["metrics"]``.
 
 See ``docs/api.md`` for the quickstart.
 """

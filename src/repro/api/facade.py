@@ -49,6 +49,7 @@ from repro.core import migration
 from repro.core.features import FeatureSpace
 from repro.core.partition import PartitionState
 from repro.graph.triples import TripleStore
+from repro.obs import span
 from repro.obs.metrics import NULL_METRICS
 from repro.query import exec as qexec
 from repro.query import plan as qplan
@@ -138,13 +139,18 @@ class PartitionedKG:
         """Materialized per-shard views (lazily built, cached until a delta
         touches the shard). A shard's view holds its primary slice plus any
         replica copies pinned onto it (``self.replicas``)."""
-        for s in range(self.state.n_shards):
-            if self._views[s] is None:
-                self._views[s] = TripleStore(
-                    self.store.triples[self.shard_rows(s)],
-                    self.store.dictionary)
-                self.view_rebuilds += 1
-                self.metrics.counter("cache.view_rebuilds").inc()
+        stale = [s for s in range(self.state.n_shards)
+                 if self._views[s] is None]
+        if stale:
+            with span("repro.facade.views") as sp:
+                if sp.recording:
+                    sp.annotate(rebuilt=len(stale))
+                for s in stale:
+                    self._views[s] = TripleStore(
+                        self.store.triples[self.shard_rows(s)],
+                        self.store.dictionary)
+                    self.view_rebuilds += 1
+                    self.metrics.counter("cache.view_rebuilds").inc()
         return list(self._views)
 
     def shard_rows(self, s: int) -> np.ndarray:
@@ -427,18 +433,19 @@ class PartitionedKG:
         candidate ``ReplicaMap``, shipping is charged against the nearest
         replica (``stats_from_profile``) — how replica-served savings enter
         the adaptation guard's benefit side."""
-        self.sync_universe()
-        triple_shard = cand.feature_to_shard[self.owners].astype(np.int32)
-        net = net or qexec.NetworkModel()
-        num = den = 0.0
-        for q in queries:
-            st = qplan.stats_from_profile(q, self.profile(q), self.space,
-                                          cand, triple_shard,
-                                          replicas=replicas,
-                                          owners=self.owners)
-            num += st.modeled_time(net) * q.frequency
-            den += q.frequency
-        return num / max(den, 1e-12)
+        with span("repro.adapt.measure"):
+            self.sync_universe()
+            triple_shard = cand.feature_to_shard[self.owners].astype(np.int32)
+            net = net or qexec.NetworkModel()
+            num = den = 0.0
+            for q in queries:
+                st = qplan.stats_from_profile(q, self.profile(q), self.space,
+                                              cand, triple_shard,
+                                              replicas=replicas,
+                                              owners=self.owners)
+                num += st.modeled_time(net) * q.frequency
+                den += q.frequency
+            return num / max(den, 1e-12)
 
     def commit(self, new_state: PartitionState) -> migration.MigrationPlan:
         """Adopt ``new_state``; returns the migration delta that was applied.

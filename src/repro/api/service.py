@@ -34,10 +34,11 @@ import numpy as np
 from repro import write as kgwrite
 from repro.core.adaptive import AdaptConfig, AdaptReport, AWAPartController
 from repro.core.features import FeatureSpace
-from repro.core.migration import TRIPLE_BYTES, MigrationChunk
+from repro.core.migration import MigrationChunk
 from repro.graph.triples import TripleStore
 from repro.migrate import MigrationSession
-from repro.obs import NULL_TRACER, MetricsRegistry, Tracer, set_ambient
+from repro.obs import (NULL_TRACER, MetricsRegistry, Tracer, set_ambient,
+                       set_ambient_tracer, span)
 from repro.query import exec as qexec
 from repro.query.pattern import Query
 
@@ -97,9 +98,9 @@ class KGService:
         self.write_log = kgwrite.WriteLog()        # applied-mutation history
         self._stream_recorder = None   # LatencyRecorder of the live stream
         # observability (repro.obs): one registry per service, always on
-        # (counters are cheap); span tracing only when asked for. The
-        # registry doubles as the ambient sink for kernel-dispatch tier
-        # counters, which have no service handle.
+        # (counters are cheap); span recording only when asked for. Both
+        # are installed ambiently, as the sinks of the kernel-dispatch
+        # counters and of the program spans, which have no service handle.
         self.metrics = MetricsRegistry()
         set_ambient(self.metrics)
         if trace is True:
@@ -108,6 +109,7 @@ class KGService:
             self._tracer = trace            # caller-owned Tracer instance
         else:
             self._tracer = NULL_TRACER
+        set_ambient_tracer(self._tracer)
 
     @classmethod
     def from_dataset(cls, ds, n_shards: int,
@@ -148,14 +150,12 @@ class KGService:
         assert self.kg is not None, "bootstrap() first"
         hit = self.kg.cached_result(q)
         cached = hit is not None
-        built0 = self.kg.plan_builds
         if hit is None:
             hit = self.executor.run(self.kg.plan(q), self.kg)
             self.kg.store_result(q, *hit)
         bindings, stats = hit
         self.observe(q, stats.modeled_time(self.net))
-        self._note_query(q, stats, cached,
-                         plan_built=self.kg.plan_builds > built0)
+        self._note_query(stats, cached)
         return bindings, stats
 
     def query_batch(self, queries: Sequence[Query],
@@ -183,43 +183,33 @@ class KGService:
         where ``miss`` indexes the queries that actually reached the
         backend (the rest were epoch-valid result-cache hits)."""
         assert self.kg is not None, "bootstrap() first"
-        results = [self.kg.cached_result(q) for q in queries]
-        miss = [i for i, r in enumerate(results) if r is None]
-        built = set()
-        if miss:
-            plans = []
-            for i in miss:
-                builds0 = self.kg.plan_builds
-                plans.append(self.kg.plan(queries[i]))
-                if self.kg.plan_builds > builds0:
-                    built.add(i)
-            for i, res in zip(miss, self.executor.run_batch(plans, self.kg)):
-                results[i] = res
-                self.kg.store_result(queries[i], *res)
-        for q, (_, stats) in zip(queries, results):
-            self.observe(q, stats.modeled_time(self.net))
-        missed = set(miss)
-        tr = self._tracer
-        if tr.enabled:
-            with tr.span("window", cat="serve", n=len(queries),
-                         misses=len(miss), epoch=self.kg.epoch):
-                for i, (q, (_, stats)) in enumerate(zip(queries, results)):
-                    self._note_query(q, stats, cached=i not in missed,
-                                     plan_built=i in built)
-        else:
+        with span("repro.serve.window") as sp:
+            results = [self.kg.cached_result(q) for q in queries]
+            miss = [i for i, r in enumerate(results) if r is None]
+            if miss:
+                plans = []
+                with span("repro.serve.plan") as psp:
+                    builds0 = self.kg.plan_builds
+                    for i in miss:
+                        plans.append(self.kg.plan(queries[i]))
+                    if psp.recording:
+                        psp.annotate(built=self.kg.plan_builds - builds0)
+                for i, res in zip(miss,
+                                  self.executor.run_batch(plans, self.kg)):
+                    results[i] = res
+                    self.kg.store_result(queries[i], *res)
+            missed = set(miss)
             for i, (q, (_, stats)) in enumerate(zip(queries, results)):
-                self._note_query(q, stats, cached=i not in missed,
-                                 plan_built=i in built)
+                self.observe(q, stats.modeled_time(self.net))
+                self._note_query(stats, cached=i not in missed)
+            if sp.recording:
+                sp.annotate(n=len(queries), misses=len(miss),
+                            epoch=self.kg.epoch)
         return results, miss
 
-    def _note_query(self, q: Query, stats: qexec.ExecStats, cached: bool,
-                    plan_built: bool) -> None:
-        """Per-query observability: registry counters always; when tracing,
-        one ``query`` span decomposed into plan→scan→join→federate→ship
-        children whose durations are exactly the ``NetworkModel`` terms of
-        ``stats.modeled_time`` — so the spans are emitted from plan+stats
-        at the service layer and their *structure* is identical across
-        executor backends (ExecStats.COMPARABLE is pinned by tests)."""
+    def _note_query(self, stats: qexec.ExecStats, cached: bool) -> None:
+        """Per-query registry counters; a miss also records its
+        ``NetworkModel`` time in the ``query.modeled_s`` histogram."""
         net = self.net or qexec.NetworkModel()
         m = self.metrics
         m.counter("queries.served").inc()
@@ -237,35 +227,6 @@ class KGService:
             m.gauge("join.expand_cap_headroom").set(
                 self.kg.max_join_rows - peak)
             m.histogram("query.modeled_s").observe(stats.modeled_time(net))
-        tr = self._tracer
-        if not tr.enabled:
-            return
-        with tr.span("query", cat="serve", query=q.name, cached=cached,
-                     epoch=self.kg.epoch, rows=stats.rows):
-            with tr.span("plan", cat="serve",
-                         dur=net.plan_s if plan_built else 0.0,
-                         built=plan_built):
-                pass
-            with tr.span("scan", cat="serve",
-                         dur=stats.scan_rows_critical / net.scan_rows_per_s,
-                         rows=stats.scan_rows_critical):
-                pass
-            with tr.span("join", cat="serve",
-                         dur=stats.join_rows / net.join_rows_per_s,
-                         rows=stats.join_rows,
-                         cross_shard=stats.distributed_joins,
-                         expanded_rows=stats.expanded_rows):
-                pass
-            with tr.span("federate", cat="serve",
-                         dur=stats.messages * net.latency_s,
-                         messages=stats.messages):
-                pass
-            with tr.span("ship", cat="serve",
-                         dur=stats.rows_shipped * net.row_bytes
-                             / net.bandwidth_Bps,
-                         rows=stats.rows_shipped,
-                         bytes=stats.bytes_shipped):
-                pass
 
     # ------------------------------------------------------------------ #
     # live writes (repro.write)
@@ -301,25 +262,19 @@ class KGService:
         data-drift signal the next adaptation round's fanout pricing and
         replica proposal consume."""
         assert self.kg is not None, "bootstrap() first"
-        report = self.kg.apply_write(batch)
-        self.write_log.append(batch, report)
-        ctrl = self.controller
-        if ctrl is not None and report.effective:
-            ctrl.note_writes(report)
-        tr = self._tracer
-        if tr.enabled:
-            net = self.net or qexec.NetworkModel()
-            traffic = (report.n_inserted + report.n_deleted) * TRIPLE_BYTES \
-                + report.fanout_bytes
-            with tr.span("write.batch", cat="write",
-                         dur=traffic / net.bandwidth_Bps,
-                         inserted=report.n_inserted,
-                         deleted=report.n_deleted,
-                         redundant=report.n_redundant,
-                         touched_shards=len(report.touched_shards),
-                         fanout_bytes=report.fanout_bytes,
-                         epoch=report.epoch):
-                pass
+        with span("repro.write.batch") as sp:
+            report = self.kg.apply_write(batch)
+            self.write_log.append(batch, report)
+            ctrl = self.controller
+            if ctrl is not None and report.effective:
+                ctrl.note_writes(report)
+            if sp.recording:
+                sp.annotate(inserted=report.n_inserted,
+                            deleted=report.n_deleted,
+                            redundant=report.n_redundant,
+                            touched_shards=len(report.touched_shards),
+                            fanout_bytes=report.fanout_bytes,
+                            epoch=report.epoch)
         return report
 
     # ------------------------------------------------------------------ #
@@ -339,8 +294,10 @@ class KGService:
         return StreamService(self, **kwargs)
 
     def tracer(self) -> Tracer:
-        """The service's span tracer (``repro.obs.Tracer``) — inspect
-        ``tracer().events`` or ``tracer().export(path)`` after a run."""
+        """The service's span recorder (``repro.obs.Tracer``), installed as
+        the ambient one when the service was built: inspect
+        ``tracer().events`` or write ``tracer().export(path)`` (Chrome
+        trace JSON on the wall clock) after a run."""
         if not self._tracer.enabled:
             raise RuntimeError(
                 "tracing is disabled for this service: construct it with "
@@ -436,11 +393,7 @@ class KGService:
                             "adaptive; use AWAPartitioner")
         m = self.metrics
         m.counter("adapt.rounds").inc()
-        # adapt is a cold path: span bookkeeping runs unconditionally (the
-        # null tracer's span is a shared no-op), so the atomic drain's chunk
-        # spans nest inside the round span without duplicated control flow
-        with self._tracer.span("adapt.round", cat="adapt",
-                               trigger=_trigger) as sp:
+        with span("repro.adapt.round") as sp:
             self.drain()                       # finish any in-flight drain
             session, report = self.partitioner.adapt(
                 self.kg, list(new_queries), net=self.net,
@@ -449,14 +402,16 @@ class KGService:
             if report.accepted and ctrl is not None:
                 ctrl.clear_window()            # fresh TM window post-migration
                 ctrl.reset_baseline(report.t_new)
-            sp.annotate(accepted=report.accepted, reason=report.reason,
-                        t_base=report.t_base, t_new=report.t_new,
-                        migration_s=report.migration_s,
-                        amortize_window=report.amortize_window,
-                        fanout_bytes=report.fanout_bytes,
-                        moves=report.plan.n_moves,
-                        chosen_cut=report.chosen_cut,
-                        n_clusters=report.n_clusters)
+            if sp.recording:
+                sp.annotate(trigger=_trigger, accepted=report.accepted,
+                            reason=report.reason, t_base=report.t_base,
+                            t_new=report.t_new,
+                            migration_s=report.migration_s,
+                            amortize_window=report.amortize_window,
+                            fanout_bytes=report.fanout_bytes,
+                            moves=report.plan.n_moves,
+                            chosen_cut=report.chosen_cut,
+                            n_clusters=report.n_clusters)
             m.counter("adapt.accepted" if report.accepted
                       else "adapt.rejected").inc()
             if report.accepted:
@@ -466,12 +421,6 @@ class KGService:
                     len(report.plan.replica_adds))
                 m.counter("replicate.planned_drops").inc(
                     len(report.plan.replica_drops))
-                with self._tracer.span(
-                        "replica.promotion", cat="replicate",
-                        adds=len(report.plan.replica_adds),
-                        drops=len(report.plan.replica_drops),
-                        replica_bytes=report.replica_bytes):
-                    pass
             if self.migration_budget is None:
                 session.drain()                # atomic: commit-now behaviour
         self.session = None if session.done else session
@@ -483,17 +432,13 @@ class KGService:
         if self.session is None:
             return None
         sess = self.session
-        chunk = sess.step()
-        if chunk is not None and self._tracer.enabled:
-            net = self.net or qexec.NetworkModel()
-            with self._tracer.span(
-                    "migration.chunk", cat="migrate",
-                    dur=chunk.bytes / net.bandwidth_Bps,
-                    moves=len(chunk.moves), bytes=chunk.bytes,
-                    replica_adds=len(chunk.replica_adds),
-                    replica_drops=len(chunk.replica_drops),
-                    progress=sess.progress(), epoch=self.kg.epoch):
-                pass
+        with span("repro.migrate.chunk") as sp:
+            chunk = sess.step()
+            if sp.recording and chunk is not None:
+                sp.annotate(moves=len(chunk.moves), bytes=chunk.bytes,
+                            replica_adds=len(chunk.replica_adds),
+                            replica_drops=len(chunk.replica_drops),
+                            progress=sess.progress(), epoch=self.kg.epoch)
         if self.session.done:
             self.session = None
             # the TM observed hybrid-layout times while draining; restart the

@@ -30,6 +30,7 @@ from repro.core.scoring import (ScoreWeights, WorkloadStats,
                                 distributed_joins, score_matrix,
                                 workload_stats)
 from repro.kernels.jaccard import ops as jaccard_ops
+from repro.obs import span
 from repro.query.pattern import Query
 
 
@@ -243,11 +244,16 @@ class AWAPartController:
     # ------------------------------------------------------------------ #
     def cluster_queries(self, queries: Sequence[Query],
                         cut: Optional[float] = None) -> np.ndarray:
-        bitmaps = self.space.workload_bitmaps(queries)
-        dist = np.asarray(jaccard_ops.jaccard_distance(bitmaps))
-        z = hac.hac_numpy(dist, self.config.linkage)
-        return hac.cut(z, cut if cut is not None
-                       else self.config.cut_distance)
+        with span("repro.adapt.cluster") as sp:
+            if sp.recording:
+                sp.annotate(queries=len(queries))
+            bitmaps = self.space.workload_bitmaps(queries)
+            with span("repro.adapt.jaccard"):
+                dist = np.asarray(jaccard_ops.jaccard_distance(bitmaps))
+            with span("repro.adapt.hac"):
+                z = hac.hac_numpy(dist, self.config.linkage)
+                return hac.cut(z, cut if cut is not None
+                               else self.config.cut_distance)
 
     def feature_groups(self, queries: Sequence[Query],
                        labels: np.ndarray) -> List[np.ndarray]:
@@ -398,8 +404,9 @@ class AWAPartController:
         self._baseline_avg = t_base if t_base is not None else self._baseline_avg
 
         # line 3: track new PO features; ownership split grows the universe
-        self.space.track_workload(queries)
-        cur, _ = migration.extend_for_space(self.state, self.space)
+        with span("repro.adapt.track"):
+            self.space.track_workload(queries)
+            cur, _ = migration.extend_for_space(self.state, self.space)
         if replicas is not None:
             # plan over the grown universe: new (split) PO features start
             # primary-only on their inherited shard, like the facade's view
@@ -411,7 +418,8 @@ class AWAPartController:
         cuts = self.config.cut_candidates or (self.config.cut_distance,)
         best = None
         for cut in cuts:
-            cand, stats, ncl = self._assign(queries, cur, cut=cut)
+            with span("repro.adapt.assign"):
+                cand, stats, ncl = self._assign(queries, cur, cut=cut)
             obj = measure(cand) if measure else distributed_joins(stats, cand)
             if best is None or obj < best[0]:
                 best = (obj, cand, stats, cut, ncl)
@@ -448,14 +456,18 @@ class AWAPartController:
         rmap_new = None
         if replicas is not None:
             from repro import replicate
-            rmap_new = replicate.propose_replicas(
-                self.space, new, queries,
-                int(getattr(cfg, "replica_budget", 0) or 0), heat=heat,
-                write_heat=wh if wh.any() else None)
+            with span("repro.replicate.promote") as promote:
+                rmap_new = replicate.propose_replicas(
+                    self.space, new, queries,
+                    int(getattr(cfg, "replica_budget", 0) or 0), heat=heat,
+                    write_heat=wh if wh.any() else None)
 
         dj_before = distributed_joins(stats, cur)
         dj_after = distributed_joins(stats, new)
         mplan = migration.plan(cur, new, replicas, rmap_new)
+        if rmap_new is not None and promote.recording:
+            promote.annotate(adds=len(mplan.replica_adds),
+                             drops=len(mplan.replica_drops))
 
         t_new = obj_new if measure else None                 # line 24
         if measure and rmap_new is not None and rmap_new.has_replicas:

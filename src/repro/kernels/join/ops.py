@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.kernels import dispatch
 from repro.kernels.join import kernel, ref
+from repro.obs import span
 
 _INT64_MAX = np.iinfo(np.int64).max
 _oracle_cache: dict = {}
@@ -151,7 +152,9 @@ _pipe_cache: dict = {}
 def _pipe_fns():
     """Jitted device helpers for the fused pipeline (and the oracle tiers
     of the granular expand op) — tiny glue ops that keep intermediates on
-    the device between kernel stages instead of punting to host numpy."""
+    the device between kernel stages instead of punting to host numpy.
+    Each is a named function, so its executable is named after its stage
+    on a profile (``jit_sort_take``, ``jit_pair_gather``, ...)."""
     import functools
 
     import jax
@@ -165,16 +168,37 @@ def _pipe_fns():
             return jnp.concatenate(
                 [a, jnp.full((n - a.shape[0],), fill, a.dtype)])
 
-        _pipe_cache.update(
-            take=jax.jit(lambda a, i: a[i]),
-            sub=jax.jit(lambda a, b: a - b),
-            clamp=jax.jit(lambda x, n: jnp.minimum(x, n)),
-            total64=jax.jit(lambda c: jnp.sum(c.astype(jnp.int64))),
+        def sort_take(a, order):
+            """The build side put in sort order."""
+            return a[order]
+
+        def pair_gather(order, pos):
+            """Expanded pair positions to build-side row ids."""
+            return order[pos]
+
+        def run_counts(hi, lo):
+            return hi - lo
+
+        def clamp_runs(x, n):
+            return jnp.minimum(x, n)
+
+        def count_total(c):
+            return jnp.sum(c.astype(jnp.int64))
+
+        def run_starts(c):
             # a device scan: about a minute to compile for the TPU at ~1M
-            # rows, so the kernel pipeline takes its prefix sums from the host
-            starts=jax.jit(lambda c: jnp.cumsum(c) - c),
-            join_words=jax.jit(lambda hi, lo: (hi.astype(jnp.int64) << 32)
-                               | lo.astype(jnp.uint32).astype(jnp.int64)),
+            # rows, so the kernel pipeline takes its prefix sums from the
+            # host
+            return jnp.cumsum(c) - c
+
+        def join_words(hi, lo):
+            return ((hi.astype(jnp.int64) << 32)
+                    | lo.astype(jnp.uint32).astype(jnp.int64))
+
+        _pipe_cache.update(
+            {f.__name__: jax.jit(f) for f in (
+                sort_take, pair_gather, run_counts, clamp_runs, count_total,
+                run_starts, join_words)},
             expand=jax.jit(ref.expand_pairs, static_argnames=("total",)),
             gather=jax.jit(ref.gather_rows, static_argnames=("fill",)),
             pad_to=pad_to,
@@ -531,33 +555,45 @@ def _pipeline_oracle(lcs, rcs, max_total):
     with jax.enable_x64(True):
         pack, search = _oracle_fns()
         fns = _pipe_fns()
-        _note(h2d=2)
-        lk_d = pack(_pad_pow2(np.stack(lcs, axis=1)))          # (nl pow2,)
-        rk_d = pack(_pad_pow2(np.stack(rcs, axis=1)))          # (nr pow2,)
-        _note(d2h=1)
-        rk = np.asarray(rk_d)[:nr]
-        order = np.argsort(rk, kind="stable")
-        _note(h2d=1)
-        order_d = jnp.asarray(order)
-        build_d = fns["pad_to"](fns["take"](rk_d[:nr], order_d),
-                                n=_pow2_len(nr), fill=int(_INT64_MAX))
-        lo_j, hi_j = search(build_d, lk_d)
-        lo_d = fns["clamp"](lo_j[:nl], nr)
-        counts_d = fns["sub"](fns["clamp"](hi_j[:nl], nr), lo_d)
-        _note(d2h=1)
-        total = int(fns["total64"](counts_d))
-        _check_total(total, max_total)
+        with span("repro.join.pack"):
+            _note(h2d=2)
+            lk_d = pack(_pad_pow2(np.stack(lcs, axis=1)))      # (nl pow2,)
+            rk_d = pack(_pad_pow2(np.stack(rcs, axis=1)))      # (nr pow2,)
+        with span("repro.join.sort"):
+            _note(d2h=1)
+            rk = np.asarray(rk_d)[:nr]
+            order = np.argsort(rk, kind="stable")
+            _note(h2d=1)
+            order_d = jnp.asarray(order)
+            build_d = fns["pad_to"](fns["sort_take"](rk_d[:nr], order_d),
+                                    n=_pow2_len(nr), fill=int(_INT64_MAX))
+        with span("repro.join.probe") as sp:
+            if sp.recording:
+                sp.annotate(tier="oracle")
+            lo_j, hi_j = search(build_d, lk_d)
+            lo_d = fns["clamp_runs"](lo_j[:nl], nr)
+            counts_d = fns["run_counts"](fns["clamp_runs"](hi_j[:nl], nr),
+                                         lo_d)
+        with span("repro.join.counts"):
+            _note(d2h=1)
+            total = int(fns["count_total"](counts_d))
+            _check_total(total, max_total)
         if total == 0:
             return _EMPTY_PAIR
-        mp = _pow2_len(nl)
-        counts_p = fns["pad_to"](counts_d, n=mp, fill=0)
-        li_d, pos_d = fns["expand"](fns["starts"](counts_p), counts_p,
-                                    fns["pad_to"](lo_d, n=mp, fill=0),
-                                    total=_pow2_len(total))
-        ri_d = fns["take"](order_d, pos_d[:total])
-        _note(d2h=2)
-        return (np.asarray(li_d[:total]).astype(np.int64),
-                np.asarray(ri_d).astype(np.int64), total)
+        with span("repro.join.expand") as sp:
+            if sp.recording:
+                sp.annotate(tier="oracle", total=total)
+            mp = _pow2_len(nl)
+            counts_p = fns["pad_to"](counts_d, n=mp, fill=0)
+            li_d, pos_d = fns["expand"](fns["run_starts"](counts_p),
+                                        counts_p,
+                                        fns["pad_to"](lo_d, n=mp, fill=0),
+                                        total=_pow2_len(total))
+        with span("repro.join.gather"):
+            ri_d = fns["pair_gather"](order_d, pos_d[:total])
+            _note(d2h=2)
+            return (np.asarray(li_d[:total]).astype(np.int64),
+                    np.asarray(ri_d).astype(np.int64), total)
 
 
 def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
@@ -587,69 +623,89 @@ def _pipeline_pallas(lcs, rcs, use_kernel, interpret, max_total):
     dispatch.note_tier("join.pipeline", "pallas",
                        "auto" if auto else "forced")
     fns = _pipe_fns()
-    _note(h2d=2)
-    lh, ll = kernel.pack_keys_pallas(
-        np.stack(lcs, axis=1).astype(np.int32), interpret=interpret)
-    rh, rl = kernel.pack_keys_pallas(
-        np.stack(rcs, axis=1).astype(np.int32), interpret=interpret)
+    with span("repro.join.pack"):
+        _note(h2d=2)
+        lh, ll = kernel.pack_keys_pallas(
+            np.stack(lcs, axis=1).astype(np.int32), interpret=interpret)
+        rh, rl = kernel.pack_keys_pallas(
+            np.stack(rcs, axis=1).astype(np.int32), interpret=interpret)
     # build-side sort on the host by design: the recombined int64 key is
     # the one mid-pipeline materialization, the order the one extra upload
-    with jax.enable_x64(True):
-        rk_d = fns["join_words"](rh, rl)
-    _note(d2h=1)
-    order = np.argsort(np.asarray(rk_d), kind="stable")
-    _note(h2d=1)
-    order_d = jnp.asarray(order.astype(np.int32))
-    rh_s = fns["take"](rh, order_d)
-    rl_s = fns["take"](rl, order_d)
-    if auto and nl * nr > _probe_work_cap():
-        # compare budget exceeded: this stage runs as the device oracle
-        dispatch.note_tier("join.pipeline.probe", "oracle", "work_cap")
+    with span("repro.join.sort"):
         with jax.enable_x64(True):
-            _, search = _oracle_fns()
-            lo_j, hi_j = search(rk_d[order_d],
-                                fns["join_words"](lh, ll))
-        lo_d = lo_j.astype(jnp.int32)
-        counts_d = fns["sub"](hi_j, lo_j).astype(jnp.int32)
-    else:
-        lo_d, hi_d = kernel.probe_sorted_pallas(rh_s, rl_s, lh, ll,
-                                                interpret=interpret)
-        counts_d = fns["sub"](hi_d, lo_d)
-    _note(d2h=1)
-    counts = np.asarray(counts_d).astype(np.int64)
-    total = int(counts.sum())
-    _check_total(total, max_total)
+            rk_d = fns["join_words"](rh, rl)
+        _note(d2h=1)
+        order = np.argsort(np.asarray(rk_d), kind="stable")
+        _note(h2d=1)
+        order_d = jnp.asarray(order.astype(np.int32))
+        rh_s = fns["sort_take"](rh, order_d)
+        rl_s = fns["sort_take"](rl, order_d)
+    with span("repro.join.probe") as sp:
+        if auto and nl * nr > _probe_work_cap():
+            # compare budget exceeded: this stage runs as the device oracle
+            dispatch.note_tier("join.pipeline.probe", "oracle", "work_cap")
+            tier = "oracle"
+            with jax.enable_x64(True):
+                _, search = _oracle_fns()
+                lo_j, hi_j = search(rk_d[order_d],
+                                    fns["join_words"](lh, ll))
+            lo_d = lo_j.astype(jnp.int32)
+            counts_d = fns["run_counts"](hi_j, lo_j).astype(jnp.int32)
+        else:
+            tier = "pallas"
+            lo_d, hi_d = kernel.probe_sorted_pallas(rh_s, rl_s, lh, ll,
+                                                    interpret=interpret)
+            counts_d = fns["run_counts"](hi_d, lo_d)
+        if sp.recording:
+            sp.annotate(tier=tier)
+    with span("repro.join.counts"):
+        _note(d2h=1)
+        counts = np.asarray(counts_d).astype(np.int64)
+        total = int(counts.sum())
+        _check_total(total, max_total)
+        if 0 < total < 1 << 31 and nr < 1 << 31:
+            _note(h2d=1)
+            starts_d = jnp.asarray(
+                (np.cumsum(counts) - counts).astype(np.int32))
     if total == 0:
         return _EMPTY_PAIR
-    if total >= 1 << 31 or nr >= 1 << 31:
-        # past the int32 envelope no device stage can carry the expansion;
-        # finish on the host (auto would normally cap out long before this)
-        dispatch.note_tier("join.pipeline.expand", "host", "int32_envelope")
-        li, pos = expand_pairs_numpy(np.asarray(lo_d).astype(np.int64),
-                                     counts)
-        return li, order[pos].astype(np.int64), total
-    _note(h2d=1)
-    starts_d = jnp.asarray((np.cumsum(counts) - counts).astype(np.int32))
-    tp = _pow2_len(total)
-    if auto and total * nl > _expand_work_cap():
-        # ownership-test budget exceeded: searchsorted oracle, on device
-        dispatch.note_tier("join.pipeline.expand", "oracle", "work_cap")
-        mp = _pow2_len(nl)
-        li_d, pos_d = fns["expand"](fns["pad_to"](starts_d, n=mp, fill=total),
-                                    fns["pad_to"](counts_d, n=mp, fill=0),
-                                    fns["pad_to"](lo_d, n=mp, fill=0),
-                                    total=tp)
-    else:
-        li_d, pos_d = kernel.expand_pairs_pallas(starts_d, counts_d, lo_d,
-                                                 total=tp,
-                                                 interpret=interpret)
-    li_d, pos_d = li_d[:total], pos_d[:total]
+    with span("repro.join.expand") as sp:
+        if total >= 1 << 31 or nr >= 1 << 31:
+            # past the int32 envelope no device stage can carry the
+            # expansion; finish on the host (auto would normally cap out
+            # long before this)
+            dispatch.note_tier("join.pipeline.expand", "host",
+                               "int32_envelope")
+            if sp.recording:
+                sp.annotate(tier="host", total=total)
+            li, pos = expand_pairs_numpy(np.asarray(lo_d).astype(np.int64),
+                                         counts)
+            return li, order[pos].astype(np.int64), total
+        tp = _pow2_len(total)
+        if auto and total * nl > _expand_work_cap():
+            # ownership-test budget exceeded: searchsorted oracle, on device
+            dispatch.note_tier("join.pipeline.expand", "oracle", "work_cap")
+            tier = "oracle"
+            mp = _pow2_len(nl)
+            li_d, pos_d = fns["expand"](
+                fns["pad_to"](starts_d, n=mp, fill=total),
+                fns["pad_to"](counts_d, n=mp, fill=0),
+                fns["pad_to"](lo_d, n=mp, fill=0), total=tp)
+        else:
+            tier = "pallas"
+            li_d, pos_d = kernel.expand_pairs_pallas(starts_d, counts_d,
+                                                     lo_d, total=tp,
+                                                     interpret=interpret)
+        if sp.recording:
+            sp.annotate(tier=tier, total=total)
+        li_d, pos_d = li_d[:total], pos_d[:total]
     # XLA's device gather: Mosaic has no lowering for a 1-D lane gather
-    dispatch.note_tier("join.pipeline.gather", "xla")
-    ri_d = fns["take"](order_d, pos_d)
-    _note(d2h=2)
-    return (np.asarray(li_d).astype(np.int64),
-            np.asarray(ri_d).astype(np.int64), total)
+    with span("repro.join.gather"):
+        dispatch.note_tier("join.pipeline.gather", "xla")
+        ri_d = fns["pair_gather"](order_d, pos_d)
+        _note(d2h=2)
+        return (np.asarray(li_d).astype(np.int64),
+                np.asarray(ri_d).astype(np.int64), total)
 
 
 def hash_join_pipeline(lcs: Sequence[np.ndarray], rcs: Sequence[np.ndarray],

@@ -18,10 +18,11 @@ read/write serving. ``--stream`` swaps the experiment for the
 continuous-admission loop (``repro.stream``): an open-loop replay at
 ``--arrival-rate`` qps with writes and the migration drain in flight,
 reporting p50/p95/p99 admission→completion tails per window.
-``--trace out.json`` records the run's ``repro.obs`` spans (per-query
-plan→scan→join→federate→ship, windows, migration chunks, adaptation
-rounds) as a Perfetto-loadable Chrome trace, and ``--metrics-csv``
-dumps the metrics-registry snapshot.
+``--trace out.json`` records the run's ``repro.obs`` spans on the wall
+clock (windows, planning, executed queries with their scans, joins and
+join stages, federation, adaptation rounds and their phases, migration
+chunks, write batches) as a Perfetto-loadable Chrome trace, and
+``--metrics-csv`` dumps the metrics-registry snapshot.
 
   PYTHONPATH=src python -m repro.launch.serve --universities 5 --shards 8 \
       --experiment 1 --executor jax --migration-budget 1048576 \
@@ -292,10 +293,11 @@ def main() -> None:
     ap.add_argument("--show-federated", action="store_true",
                     help="print a federated SPARQL rewrite example")
     ap.add_argument("--trace", metavar="PATH", default=None,
-                    help="record repro.obs spans (per-query plan/scan/join/"
-                         "federate/ship, windows, migration chunks, "
-                         "adaptation rounds) and export a Chrome-trace JSON "
-                         "(.jsonl for JSON-lines) to PATH")
+                    help="record the repro.obs spans on the wall clock "
+                         "(windows, planning, queries with their scans, "
+                         "joins and federation, adaptation rounds, "
+                         "migration chunks, writes) and export them as "
+                         "Chrome-trace JSON to PATH")
     ap.add_argument("--metrics-csv", metavar="PATH", default=None,
                     help="dump the service's metrics-registry snapshot "
                          "(counters/gauges/histograms) as CSV to PATH")

@@ -1,24 +1,28 @@
-"""repro.obs — unified tracing + metrics for the serving, adaptation,
+"""repro.obs — program spans and metrics for the serving, adaptation,
 and kernel stack.
 
-* :class:`Tracer` / :class:`Span`: structured spans on the modeled
-  virtual clock (per-query plan→scan→join→federate→ship, window,
-  migration-chunk, replica-promotion, write-batch, adaptation-round),
-  exported as Chrome trace-event JSON (Perfetto-loadable) or JSONL.
-  Byte-identical across runs for a fixed seed/executor.
+* :func:`span`: the one span call of every instrumented site. It measures
+  real time, shows on a profiler capture's host plane
+  (``jax.profiler.TraceAnnotation``) beside the device's operations,
+  accumulates ``span.<name>.calls`` / ``span.<name>.ns`` in the ambient
+  metrics registry, and, when a :class:`Tracer` is installed
+  (:func:`set_ambient_tracer`, or ``KGService(trace=True)``), is kept in
+  memory with its parent and request id and exported as Chrome trace JSON
+  (Perfetto-loadable).
 * :class:`MetricsRegistry`: central counters/gauges/histograms threaded
   through the facade, executors, stream, migrate, replicate, write, and
   kernel dispatch; snapshot folded into ``KGService.stats()``.
-* ``NULL_TRACER`` / ``NULL_METRICS``: inert defaults — observability is
-  off unless asked for, at the cost of one attribute check per site.
+* ``NULL_TRACER`` / ``NULL_METRICS``: inert defaults.
 """
 from repro.obs.metrics import (NULL_METRICS, Counter, Gauge, Histogram,
                                MetricsRegistry, NullRegistry, ambient,
                                set_ambient)
-from repro.obs.tracer import NULL_TRACER, NullTracer, Span, Tracer
+from repro.obs.tracer import (NULL_TRACER, NullTracer, Span, Tracer,
+                              ambient_tracer, set_ambient_tracer, span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NullRegistry",
     "NULL_METRICS", "ambient", "set_ambient",
-    "Span", "Tracer", "NullTracer", "NULL_TRACER",
+    "Span", "Tracer", "NullTracer", "NULL_TRACER", "span",
+    "set_ambient_tracer", "ambient_tracer",
 ]

@@ -13,9 +13,10 @@ and named with dotted paths (``federation.bytes_shipped``,
 output is deterministic; ``to_csv`` emits a standalone file that
 ``results/make_table.py`` renders as a ``metrics_table``.
 
-``kernels.dispatch`` has no service handle, so the module also keeps an
-*ambient* registry hook: the most recently constructed service installs
-its registry via :func:`set_ambient`, and dispatch-tier counters land
+``kernels.dispatch`` and the program spans (``repro.obs.span``) have no
+service handle, so the module also keeps an *ambient* registry hook: the
+most recently constructed service installs its registry via
+:func:`set_ambient`, and dispatch-tier counters and span times land
 there. ``NULL_METRICS`` is the inert default for facades built outside
 a service.
 """
@@ -92,6 +93,7 @@ class MetricsRegistry:
 
     def __init__(self):
         self._instruments: Dict[str, Any] = {}
+        self._spans: Dict[str, tuple] = {}
 
     def __len__(self) -> int:
         return len(self._instruments)
@@ -113,6 +115,16 @@ class MetricsRegistry:
 
     def histogram(self, name: str) -> Histogram:
         return self._get(name, Histogram)
+
+    def add_span(self, name: str, ns: int) -> None:
+        """Count one closed program span (``repro.obs.span``) and its
+        nanoseconds under ``span.<name>.calls`` and ``span.<name>.ns``."""
+        pair = self._spans.get(name)
+        if pair is None:
+            pair = self._spans[name] = (self.counter(f"span.{name}.calls"),
+                                        self.counter(f"span.{name}.ns"))
+        pair[0].value += 1
+        pair[1].value += ns
 
     def snapshot(self) -> Dict[str, Any]:
         """Deterministic nested dict: ``counters`` / ``gauges`` map name
@@ -205,7 +217,7 @@ class NullRegistry:
 NULL_METRICS = NullRegistry()
 
 # Ambient registry for call sites with no service handle (kernel
-# dispatch). The latest-constructed KGService owns it; None before any
+# dispatch, program spans). The latest-constructed KGService owns it; None before any
 # service exists.
 _AMBIENT: Optional[MetricsRegistry] = None
 

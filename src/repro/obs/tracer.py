@@ -1,36 +1,46 @@
-"""Structured span tracer on the modeled (virtual) clock.
+"""Program spans on the profiler's clock.
 
-Every timestamp comes from the deterministic cost model — the
-``NetworkModel`` arithmetic that prices scans, joins, federation
-round-trips, shipped bytes, migration chunks, and write fan-out — never
-from the wall clock. Two runs with the same seed and executor therefore
-produce *byte-identical* trace files, which makes traces first-class,
-testable artifacts rather than best-effort diagnostics.
+``span(name, **attrs)`` is the one span call every instrumented site
+makes. A span measures the real time of the block it wraps and reaches up
+to three sinks:
 
-Layout: the tracer keeps a virtual-clock cursor ``now``. A span opens at
-the cursor, and closing it moves the cursor to ``max(now, ts + dur)`` —
-so sibling spans lay out sequentially and a parent's extent covers its
-children (a parent opened with ``dur=0`` ends exactly where its last
-child ended). ``advance_to`` lets the stream loop sync the cursor to its
-own admission clock between windows.
+* a ``jax.profiler.TraceAnnotation(name)``, always. While a profiler
+  session runs (``jax.profiler.trace``) the span lands on the profile's
+  host plane, on the same clock as the device's operations; with no
+  session it costs about half a microsecond to enter and exit.
+* the ambient :class:`~repro.obs.metrics.MetricsRegistry`, always: the
+  counters ``span.<name>.calls`` and ``span.<name>.ns`` (the span's
+  nanoseconds) accumulate, so a caller that diffs two registry snapshots
+  reads each span's time over that stretch with no profiler running.
+* the ambient :class:`Tracer`, when one is installed
+  (:func:`set_ambient_tracer`; ``KGService(trace=True)`` installs its
+  own). It keeps each span in memory with its start and duration on
+  ``time.time_ns()`` (CLOCK_REALTIME, the clock the profiler's host plane
+  counts from: its events are offsets from the profile's
+  ``profile_start_time`` on that clock), the ``seq`` of its parent, a
+  request id shared by every span opened under one outermost span (one
+  ``serve_window`` call, one adaptation round), and its attributes.
+  :meth:`Tracer.export` writes Chrome trace JSON that Perfetto loads.
 
-Export targets:
+Attributes are recorded only by a Tracer. A hot site therefore opens its
+span by name alone and attaches attributes under ``if sp.recording``, so
+with no Tracer installed it builds no attribute dict.
 
-* Chrome trace-event JSON (``{"traceEvents": [...]}``, "X" complete
-  events) — loads directly in Perfetto / ``chrome://tracing``.
-* JSONL — one event per line, for grep/jq pipelines.
-
-The no-op path: ``NULL_TRACER`` shares one inert span, ``enabled`` is
-False, and every method returns immediately — hot call sites guard span
-construction with ``if tracer.enabled`` so tracing off-by-default costs
-a single attribute check per site.
+``NULL_TRACER`` is the ambient default: ``enabled`` is False and it
+records nothing.
 """
 from __future__ import annotations
 
 import json
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER"]
+from jax.profiler import TraceAnnotation
+
+from repro.obs import metrics as _metrics
+
+__all__ = ["Span", "Tracer", "NullTracer", "NULL_TRACER", "span",
+           "set_ambient_tracer", "ambient_tracer"]
 
 
 def _clean(value):
@@ -48,86 +58,98 @@ def _clean(value):
 
 
 class Span:
-    """One timed region on the modeled clock. Context manager; closing
-    records the event and advances the tracer's cursor past it."""
+    """One timed block; context manager returned by :func:`span`."""
 
-    __slots__ = ("tracer", "name", "cat", "ts", "dur", "attrs", "seq",
-                 "depth")
+    __slots__ = ("name", "attrs", "tracer", "event", "seq", "parent", "req",
+                 "depth", "t0", "_ann")
 
-    def __init__(self, tracer, name, cat, ts, dur, attrs, seq, depth):
-        self.tracer = tracer
+    def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
-        self.cat = cat
-        self.ts = ts
-        self.dur = dur
         self.attrs = attrs
-        self.seq = seq
-        self.depth = depth
+        self.tracer: Optional[Tracer] = None
+        self.event: Optional[Dict[str, Any]] = None
+
+    @property
+    def recording(self) -> bool:
+        """True when a Tracer records this span: guard attribute work."""
+        return self.tracer is not None
 
     def annotate(self, **attrs) -> "Span":
-        """Attach attributes discovered after the span opened (accept
-        decisions, realized counts)."""
-        self.attrs.update(attrs)
+        """Attach attributes found out while or after the block ran (a
+        span already closed updates its recorded event). Records nothing
+        unless a Tracer is recording the span."""
+        if self.tracer is not None:
+            if self.event is not None:
+                self.event["args"].update(
+                    {k: _clean(v) for k, v in attrs.items()})
+            else:
+                self.attrs.update(attrs)
         return self
 
     def __enter__(self) -> "Span":
+        tr = _ambient_tracer
+        if tr.enabled:
+            self.tracer = tr
+            tr._open(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        self.tracer._close(self)
+        t1 = time.time_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        reg = _metrics.ambient()
+        if reg is not None:
+            reg.add_span(self.name, t1 - self.t0)
+        if self.tracer is not None:
+            self.tracer._close(self, t1)
+
+
+def span(name: str, **attrs) -> Span:
+    """Open a program span: ``with span("repro.exec.scan") as sp: ...``."""
+    return Span(name, attrs)
 
 
 class Tracer:
-    """Span recorder on a virtual clock, starting at ``clock0`` seconds."""
+    """In-memory recorder of the spans opened while it is ambient."""
 
     enabled = True
 
-    def __init__(self, clock0: float = 0.0):
+    def __init__(self):
         self.events: List[Dict[str, Any]] = []
-        self.now = float(clock0)
         self._stack: List[Span] = []
         self._seq = 0
+        self._req = 0
 
     def __len__(self) -> int:
         return len(self.events)
 
-    # -- recording -----------------------------------------------------
-    def span(self, name: str, cat: str = "serve", dur: float = 0.0,
-             **attrs) -> Span:
-        """Open a span at the cursor. ``dur`` is the modeled duration in
-        seconds; children opened before the span closes extend it."""
-        sp = Span(self, name, cat, self.now, float(dur),
-                  {k: _clean(v) for k, v in attrs.items()},
-                  self._seq, len(self._stack))
+    def _open(self, sp: Span) -> None:
+        sp.seq = self._seq
         self._seq += 1
+        if self._stack:
+            top = self._stack[-1]
+            sp.parent, sp.req = top.seq, top.req
+        else:
+            sp.parent, sp.req = None, self._req
+            self._req += 1
+        sp.depth = len(self._stack)
         self._stack.append(sp)
-        return sp
 
-    def instant(self, name: str, cat: str = "mark", **attrs) -> None:
-        """Zero-duration event at the cursor (drift triggers, rejects)."""
-        with self.span(name, cat=cat, dur=0.0, **attrs):
-            pass
-
-    def _close(self, sp: Span) -> None:
-        end = max(self.now, sp.ts + sp.dur)
-        self.events.append(dict(seq=sp.seq, name=sp.name, cat=sp.cat,
-                                ts=sp.ts, dur=end - sp.ts, depth=sp.depth,
-                                args=sp.attrs))
-        self.now = end
+    def _close(self, sp: Span, t1: int) -> None:
+        sp.event = dict(seq=sp.seq, name=sp.name, ts_ns=sp.t0,
+                        dur_ns=t1 - sp.t0, parent=sp.parent, req=sp.req,
+                        depth=sp.depth,
+                        args={k: _clean(v) for k, v in sp.attrs.items()})
+        self.events.append(sp.event)
         if self._stack and self._stack[-1] is sp:
             self._stack.pop()
 
-    # -- clock ---------------------------------------------------------
-    def advance_to(self, t: float) -> None:
-        """Monotone sync: move the cursor forward to the caller's clock
-        (never backward — earlier spans already occupy that range)."""
-        if t > self.now:
-            self.now = float(t)
-
     # -- introspection (tests, smoke checks) ---------------------------
     def structure(self) -> List[Tuple[int, str]]:
-        """(depth, name) pairs in span *open* order — the executor- and
-        timing-independent shape of the trace."""
+        """(depth, name) pairs in span *open* order: the timing-free shape
+        of the trace."""
         return [(e["depth"], e["name"])
                 for e in sorted(self.events, key=lambda e: e["seq"])]
 
@@ -142,78 +164,35 @@ class Tracer:
 
     # -- export --------------------------------------------------------
     def chrome_trace(self) -> Dict[str, Any]:
-        """Chrome trace-event dict: "X" complete events, microsecond
-        timestamps, single pid/tid (the modeled system is one timeline)."""
+        """Chrome trace-event dict: "X" complete events with microsecond
+        timestamps on the wall clock; each event's ``args`` carry its
+        ``seq``, ``parent`` and ``req`` besides its attributes."""
         evs: List[Dict[str, Any]] = [
             dict(name="process_name", ph="M", pid=0, tid=0,
-                 args=dict(name="repro.kg (modeled clock)")),
-            dict(name="thread_name", ph="M", pid=0, tid=0,
-                 args=dict(name="virtual")),
+                 args=dict(name="repro (wall clock)")),
         ]
         for e in sorted(self.events, key=lambda e: e["seq"]):
-            evs.append(dict(name=e["name"], cat=e["cat"], ph="X",
-                            ts=round(e["ts"] * 1e6, 3),
-                            dur=round(e["dur"] * 1e6, 3),
-                            pid=0, tid=0, args=e["args"]))
+            evs.append(dict(name=e["name"], ph="X", ts=e["ts_ns"] / 1e3,
+                            dur=e["dur_ns"] / 1e3, pid=0, tid=0,
+                            args=dict(e["args"], seq=e["seq"],
+                                      parent=e["parent"], req=e["req"])))
         return {"traceEvents": evs, "displayTimeUnit": "ms"}
 
-    def to_json(self) -> str:
-        """Canonical serialization — sorted keys, no whitespace — so a
-        fixed seed/executor yields a byte-identical file."""
-        return json.dumps(self.chrome_trace(), sort_keys=True,
-                          separators=(",", ":"))
-
-    def to_jsonl(self) -> str:
-        lines = [json.dumps(e, sort_keys=True, separators=(",", ":"))
-                 for e in sorted(self.events, key=lambda e: e["seq"])]
-        return "\n".join(lines) + ("\n" if lines else "")
-
     def export(self, path: str) -> int:
-        """Write the trace to ``path`` (`.jsonl` → JSONL, else Chrome
-        trace JSON). Returns the number of span events written."""
-        text = self.to_jsonl() if path.endswith(".jsonl") else self.to_json()
+        """Write Chrome trace JSON to ``path``; returns the span count."""
         with open(path, "w") as fh:
-            fh.write(text)
+            json.dump(self.chrome_trace(), fh)
         return len(self.events)
 
 
-class _NullSpan:
-    """Shared inert span: context manager + annotate, records nothing."""
-
-    __slots__ = ()
-
-    def annotate(self, **attrs):
-        return self
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return None
-
-
 class NullTracer:
-    """Off-by-default tracer: every method is a no-op returning the one
-    shared inert span. ``enabled`` is False so hot sites can skip even
-    building attribute dicts."""
+    """The ambient default: records nothing."""
 
     enabled = False
-    _SPAN = _NullSpan()
-
     events: List[Dict[str, Any]] = []
-    now = 0.0
 
     def __len__(self) -> int:
         return 0
-
-    def span(self, name, cat="serve", dur=0.0, **attrs):
-        return self._SPAN
-
-    def instant(self, name, cat="mark", **attrs):
-        return None
-
-    def advance_to(self, t):
-        return None
 
     def structure(self):
         return []
@@ -226,3 +205,16 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+
+_ambient_tracer: "Tracer | NullTracer" = NULL_TRACER
+
+
+def set_ambient_tracer(tracer: "Tracer | NullTracer") -> None:
+    """Install the Tracer that records the spans opened from now on
+    (``NULL_TRACER`` to record none)."""
+    global _ambient_tracer
+    _ambient_tracer = tracer
+
+
+def ambient_tracer() -> "Tracer | NullTracer":
+    return _ambient_tracer

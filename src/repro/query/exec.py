@@ -16,7 +16,14 @@ Two backends behind one ``Executor`` protocol:
   pattern in the window is ONE dispatched scatter-add (``bincount`` over
   ``triple_shard[match]`` segments) instead of a python loop per shard per
   query. Bindings and stats match the numpy backend exactly (modulo row
-  order and the informational ``wall_s``).
+  order).
+
+Both backends open the same program spans (``repro.obs.span``) where they
+do the same work: ``repro.exec.batch`` > ``repro.exec.query`` >
+``repro.exec.scan`` / ``repro.exec.join``, and ``repro.exec.federation``
+for the federation accounting (once per query pattern on numpy, once per
+batch on jax). Those spans carry the real time; ``ExecStats`` carries the
+counts the ``NetworkModel`` prices.
 
 Execution model mirrors the paper's federated SPARQL (Sec. IV): a query runs
 at its Primary Processing Node (PPN) and every triple pattern whose matches
@@ -28,13 +35,13 @@ container has no cluster fabric), which lives solely in
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Protocol, Sequence, Tuple, \
     runtime_checkable
 
 import numpy as np
 
 from repro.core.migration import TRIPLE_BYTES
+from repro.obs import span
 from repro.query import plan as qplan
 from repro.query.pattern import Query, is_var
 
@@ -85,7 +92,6 @@ class ExecStats:
     rows: int = 0
     cartesian_rows: int = 0            # cross-product rows materialized
     expanded_rows: int = 0             # ragged hash-join pairs materialized
-    wall_s: float = 0.0                # actual local execution time (info)
 
     # every field that must agree between backends / profile re-accounting
     COMPARABLE = ("scan_rows_critical", "join_rows", "distributed_joins",
@@ -247,8 +253,19 @@ class NumpyExecutor:
         self.max_join_rows = max_join_rows
 
     def run(self, plan: qplan.QueryPlan, kg) -> Tuple[Bindings, ExecStats]:
+        with span("repro.exec.query") as qsp:
+            table, stats = self._run(plan, kg)
+            if qsp.recording:
+                qsp.annotate(query=plan.query.name, rows=stats.rows,
+                             modeled_s=stats.modeled_time())
+        m = getattr(kg, "metrics", None)
+        if m is not None:          # repro.obs: backend execution counters
+            m.counter("executor.queries").inc()
+        return table or {}, stats
+
+    def _run(self, plan: qplan.QueryPlan, kg,
+             ) -> Tuple[Optional[Bindings], ExecStats]:
         stats = ExecStats()
-        t0 = time.perf_counter()
         shards = kg.shards
         # replicated layout: shard views hold read copies, so every triple
         # is scanned exactly once at its *read* shard for this query — the
@@ -261,53 +278,62 @@ class NumpyExecutor:
         table: Optional[Bindings] = None
         for op in plan.ops:
             s, p, o = op.pattern
-            if read is None:
-                per_shard = [sh.match(None if is_var(s) else s,
-                                      None if is_var(p) else p,
-                                      None if is_var(o) else o)
-                             for sh in shards]
-            else:
-                per_shard = []
-                for s_idx, sh in enumerate(shards):
-                    vidx = sh.match_indices(None if is_var(s) else s,
-                                            None if is_var(p) else p,
-                                            None if is_var(o) else o)
-                    keep = read[kg.shard_rows(s_idx)[vidx]] == s_idx
-                    per_shard.append(sh.triples[vidx[keep]])
-            rows = (np.concatenate(per_shard, axis=0)
-                    if any(len(m) for m in per_shard)
-                    else np.empty((0, 3), np.int32))
+            with span("repro.exec.scan") as sc:
+                if read is None:
+                    per_shard = [sh.match(None if is_var(s) else s,
+                                          None if is_var(p) else p,
+                                          None if is_var(o) else o)
+                                 for sh in shards]
+                else:
+                    per_shard = []
+                    for s_idx, sh in enumerate(shards):
+                        vidx = sh.match_indices(None if is_var(s) else s,
+                                                None if is_var(p) else p,
+                                                None if is_var(o) else o)
+                        keep = read[kg.shard_rows(s_idx)[vidx]] == s_idx
+                        per_shard.append(sh.triples[vidx[keep]])
+                rows = (np.concatenate(per_shard, axis=0)
+                        if any(len(m) for m in per_shard)
+                        else np.empty((0, 3), np.int32))
+                if sc.recording:
+                    sc.annotate(rows=len(rows))
             # shards scan their slices in parallel: pay the slowest
             stats.scan_rows_critical += max(
                 (len(m) for m in per_shard), default=0)
             # federation accounting: matches living off-PPN are shipped
-            for s_idx, m in enumerate(per_shard):
-                if s_idx != plan.ppn and len(m) > 0:
-                    stats.messages += 1
-                    stats.rows_shipped += len(m)
-                    stats.bytes_shipped += len(m) * TRIPLE_BYTES
-                    if multi:
-                        stats.distributed_joins += 1
+            with span("repro.exec.federation"):
+                for s_idx, m in enumerate(per_shard):
+                    if s_idx != plan.ppn and len(m) > 0:
+                        stats.messages += 1
+                        stats.rows_shipped += len(m)
+                        stats.bytes_shipped += len(m) * TRIPLE_BYTES
+                        if multi:
+                            stats.distributed_joins += 1
             before = _table_len(table)
-            table = _join_numpy(table, op.pattern, rows, stats,
-                                self.max_join_rows)
+            if table is None:
+                table = _pattern_cols(op.pattern, rows)
+            else:
+                with span("repro.exec.join") as jsp:
+                    if jsp.recording:
+                        jsp.annotate(left=before, right=len(rows),
+                                     tier="numpy")
+                    table = _join_numpy(table, op.pattern, rows, stats,
+                                        self.max_join_rows)
             stats.join_rows += before + len(rows) + _table_len(table)
             if table is not None and _table_len(table) == 0:
                 break
-        stats.wall_s = time.perf_counter() - t0
         stats.rows = _table_len(table)
-        m = getattr(kg, "metrics", None)
-        if m is not None:          # repro.obs: backend execution counters
-            m.counter("executor.queries").inc()
-            m.histogram("executor.wall_s").observe(stats.wall_s)
-        return table or {}, stats
+        return table, stats
 
     def run_batch(self, plans: Sequence[qplan.QueryPlan], kg,
                   ) -> List[Tuple[Bindings, ExecStats]]:
-        m = getattr(kg, "metrics", None)
-        if m is not None:
-            m.counter("executor.batches").inc()
-        return [self.run(p, kg) for p in plans]
+        with span("repro.exec.batch") as sp:
+            if sp.recording:
+                sp.annotate(plans=len(plans))
+            m = getattr(kg, "metrics", None)
+            if m is not None:
+                m.counter("executor.batches").inc()
+            return [self.run(p, kg) for p in plans]
 
 
 # --------------------------------------------------------------------------- #
@@ -339,26 +365,31 @@ def _join_jax(table: Optional[Bindings], pat, rows: np.ndarray,
     cols = _pattern_cols(pat, rows) if cols is None else cols
     if table is None:
         return cols
-    shared = [v for v in cols if v in table]
-    if not shared:
+    with span("repro.exec.join") as sp:
+        shared = [v for v in cols if v in table]
         nl, nr = _table_len(table), len(next(iter(cols.values())))
-        li, ri = _cartesian_indices(nl, nr, stats, max_rows)
-    else:
-        lcs, rcs = _key_columns(table, cols, shared)
-        mode, force = probe
-        try:
-            li, ri, total = join_ops.hash_join_pipeline(
-                lcs, rcs, mode=mode, use_kernel=force, max_total=max_rows)
-        except join_ops.ExpansionCapExceeded as e:
-            raise JoinCapExceeded(
-                f"{e}; raise Executor(max_join_rows=...) or add a more "
-                "selective pattern") from None
-        stats.expanded_rows += total
-    out: Bindings = {v: c[li] for v, c in table.items()}
-    for v, c in cols.items():
-        if v not in out:
-            out[v] = c[ri]
-    return out
+        if sp.recording:
+            sp.annotate(left=nl, right=nr,
+                        tier=probe[0] if shared else "cartesian")
+        if not shared:
+            li, ri = _cartesian_indices(nl, nr, stats, max_rows)
+        else:
+            lcs, rcs = _key_columns(table, cols, shared)
+            mode, force = probe
+            try:
+                li, ri, total = join_ops.hash_join_pipeline(
+                    lcs, rcs, mode=mode, use_kernel=force,
+                    max_total=max_rows)
+            except join_ops.ExpansionCapExceeded as e:
+                raise JoinCapExceeded(
+                    f"{e}; raise Executor(max_join_rows=...) or add a more "
+                    "selective pattern") from None
+            stats.expanded_rows += total
+        out: Bindings = {v: c[li] for v, c in table.items()}
+        for v, c in cols.items():
+            if v not in out:
+                out[v] = c[ri]
+        return out
 
 
 def _federation_bincounts(shard_ids_list: Sequence[np.ndarray],
@@ -430,6 +461,13 @@ class JaxExecutor:
 
     def run_batch(self, plans: Sequence[qplan.QueryPlan], kg,
                   ) -> List[Tuple[Bindings, ExecStats]]:
+        with span("repro.exec.batch") as sp:
+            if sp.recording:
+                sp.annotate(plans=len(plans))
+            return self._run_batch(plans, kg, sp.recording)
+
+    def _run_batch(self, plans: Sequence[qplan.QueryPlan], kg,
+                   recording: bool) -> List[Tuple[Bindings, ExecStats]]:
         store = kg.store
         triple_shard = kg.triple_shard
         probe = self._probe_spec()
@@ -438,37 +476,43 @@ class JaxExecutor:
         match_cache: Dict[tuple, tuple] = {}
 
         results: List[Tuple[Bindings, ExecStats]] = []
+        query_spans = []
         executed: List[Tuple[int, tuple]] = []         # (query, pattern)
         for qi, plan in enumerate(plans):
-            stats = ExecStats()
-            t0 = time.perf_counter()
-            table: Optional[Bindings] = None
-            ops_run = 0
-            for op in plan.ops:
-                hit = match_cache.get(op.pattern)
-                if hit is None:
-                    s, p, o = op.pattern
-                    idx = store.match_indices(None if is_var(s) else s,
-                                              None if is_var(p) else p,
-                                              None if is_var(o) else o)
-                    rows = store.triples[idx]
-                    hit = (idx, rows, _pattern_cols(op.pattern, rows))
-                    match_cache[op.pattern] = hit
-                idx, rows, cols = hit
-                executed.append((qi, op.pattern))
-                ops_run += 1
-                before = _table_len(table)
-                table = _join_jax(table, op.pattern, rows, stats,
-                                  self.max_join_rows, probe, cols=cols)
-                stats.join_rows += before + len(rows) + _table_len(table)
-                if table is not None and _table_len(table) == 0:
-                    break
-            if table is not None and ops_run == 1:
-                # single-op result IS the cached column dict: copy so two
-                # queries in the window never alias the same binding arrays
-                table = {v: c.copy() for v, c in table.items()}
-            stats.rows = _table_len(table)
-            stats.wall_s = time.perf_counter() - t0
+            with span("repro.exec.query") as qsp:
+                stats = ExecStats()
+                table: Optional[Bindings] = None
+                ops_run = 0
+                for op in plan.ops:
+                    hit = match_cache.get(op.pattern)
+                    if hit is None:
+                        s, p, o = op.pattern
+                        with span("repro.exec.scan") as sc:
+                            idx = store.match_indices(
+                                None if is_var(s) else s,
+                                None if is_var(p) else p,
+                                None if is_var(o) else o)
+                            rows = store.triples[idx]
+                            hit = (idx, rows, _pattern_cols(op.pattern, rows))
+                            if sc.recording:
+                                sc.annotate(rows=len(idx))
+                        match_cache[op.pattern] = hit
+                    idx, rows, cols = hit
+                    executed.append((qi, op.pattern))
+                    ops_run += 1
+                    before = _table_len(table)
+                    table = _join_jax(table, op.pattern, rows, stats,
+                                      self.max_join_rows, probe, cols=cols)
+                    stats.join_rows += before + len(rows) + _table_len(table)
+                    if table is not None and _table_len(table) == 0:
+                        break
+                if table is not None and ops_run == 1:
+                    # single-op result IS the cached column dict: copy so
+                    # two queries in the window never alias the same
+                    # binding arrays
+                    table = {v: c.copy() for v, c in table.items()}
+                stats.rows = _table_len(table)
+            query_spans.append(qsp)
             results.append((table or {}, stats))
 
         # one dispatched batch prices the federation of every distinct
@@ -476,45 +520,46 @@ class JaxExecutor:
         # serving shard of a match depends on the query's PPN (its local
         # copies serve for free), so entries are keyed per (pattern, ppn)
         # and gathered through the facade's cached read_shard(ppn).
-        t0 = time.perf_counter()
-        replicated = _has_replicated_layout(kg)
-        if replicated:
-            keys = [(pat, plans[qi].ppn) for qi, pat in executed]
-            distinct = list(dict.fromkeys(keys))
-            idx_lists = [kg.read_shard(ppn)[match_cache[pat][0]]
-                         for pat, ppn in distinct]
-        else:
-            keys = [pat for _, pat in executed]
-            distinct = list(match_cache)
-            idx_lists = [triple_shard[match_cache[pat][0]]
-                         for pat in distinct]
-        counts = _federation_bincounts(idx_lists, kg.n_shards)
-        count_of = dict(zip(distinct, counts))
-        for key, (qi, pat) in zip(keys, executed):
-            stats = results[qi][1]
-            plan = plans[qi]
-            per_shard = count_of[key]
-            stats.scan_rows_critical += int(per_shard.max())
-            off = per_shard.copy()
-            off[plan.ppn] = 0
-            nz = int((off > 0).sum())
-            stats.messages += nz
-            stats.rows_shipped += int(off.sum())
-            stats.bytes_shipped += int(off.sum()) * TRIPLE_BYTES
-            if plan.n_patterns > 1:
-                stats.distributed_joins += nz
-        if plans:
-            acct = (time.perf_counter() - t0) / len(plans)
-            for _, stats in results:
-                stats.wall_s += acct
+        with span("repro.exec.federation") as fsp:
+            replicated = _has_replicated_layout(kg)
+            if replicated:
+                keys = [(pat, plans[qi].ppn) for qi, pat in executed]
+                distinct = list(dict.fromkeys(keys))
+                idx_lists = [kg.read_shard(ppn)[match_cache[pat][0]]
+                             for pat, ppn in distinct]
+            else:
+                keys = [pat for _, pat in executed]
+                distinct = list(match_cache)
+                idx_lists = [triple_shard[match_cache[pat][0]]
+                             for pat in distinct]
+            counts = _federation_bincounts(idx_lists, kg.n_shards)
+            count_of = dict(zip(distinct, counts))
+            for key, (qi, pat) in zip(keys, executed):
+                stats = results[qi][1]
+                plan = plans[qi]
+                per_shard = count_of[key]
+                stats.scan_rows_critical += int(per_shard.max())
+                off = per_shard.copy()
+                off[plan.ppn] = 0
+                nz = int((off > 0).sum())
+                stats.messages += nz
+                stats.rows_shipped += int(off.sum())
+                stats.bytes_shipped += int(off.sum()) * TRIPLE_BYTES
+                if plan.n_patterns > 1:
+                    stats.distributed_joins += nz
+            if fsp.recording:
+                fsp.annotate(entries=len(distinct))
+        if recording:
+            # the federation step completes each query's stats
+            for qsp, plan, (_, stats) in zip(query_spans, plans, results):
+                qsp.annotate(query=plan.query.name, rows=stats.rows,
+                             modeled_s=stats.modeled_time())
         m = getattr(kg, "metrics", None)
         if m is not None:          # repro.obs: backend execution counters
             m.counter("executor.batches").inc()
             m.counter("executor.queries").inc(len(plans))
             m.counter("executor.match_dedup_hits").inc(
                 len(executed) - len(match_cache))
-            for _, stats in results:
-                m.histogram("executor.wall_s").observe(stats.wall_s)
         return results
 
 

@@ -172,10 +172,6 @@ class StreamService:
         if not self._queue:
             return 0
         t0 = max(self.now, self._queue[0].arrival_s)
-        # sync the span tracer's virtual-clock cursor to the admission
-        # clock: spans emitted by the writes/chunks/window below lay out
-        # from this window's start (monotone — never rewinds over spans)
-        svc._tracer.advance_to(t0)
         idle = t0 - self.now
         avail = (self._credit + idle) if self.pipeline else 0.0
         overhead = 0.0
@@ -220,7 +216,6 @@ class StreamService:
 
         if not window:       # mutation-only pump: charge the unhidden stall
             self.now = t0 + max(0.0, overhead - avail)
-            svc._tracer.advance_to(self.now)
             if self.pipeline:
                 self._credit = max(0.0, avail - overhead)
             return 0
@@ -274,7 +269,6 @@ class StreamService:
         m.counter("stream.hidden_s_total").inc(hidden)
         self.n_windows += 1
         self.now = finish
-        svc._tracer.advance_to(finish)
         # double buffering: the next window's stalls can hide behind this
         # window's execution — and behind nothing else
         self._credit = exec_s if self.pipeline else 0.0

@@ -1,102 +1,145 @@
-"""repro.obs: the modeled-clock span tracer and the metrics registry —
-unit behavior (nesting, clock, null twins, kind discipline) plus THE
-observability acceptance properties: traces are byte-identical across
-same-seed runs, span *structure* is identical across executor backends,
-and a traced drain records every adaptation round, migration chunk and
-per-query plan→ship decomposition."""
+"""repro.obs: wall-clock program spans and the metrics registry — unit
+behavior (nesting, request ids, export, null twins, kind discipline) plus
+the observability acceptance properties: a traced drain records every
+adaptation round, migration chunk and window, span structure matches
+across runs and executor backends, and the spans reach a profiler
+capture's host plane on the Tracer's clock."""
+import glob
 import json
+import os
+import time
 
 import pytest
 
 from repro.api import KGService
 from repro.obs import (NULL_METRICS, NULL_TRACER, MetricsRegistry,
-                       NullTracer, Tracer)
+                       NullTracer, Tracer, ambient_tracer, set_ambient,
+                       set_ambient_tracer, span)
 from repro.stream import LatencyRecorder, QueryLatency
 
 EXECUTORS = ("numpy", "jax", "jax-pallas")
+
+
+@pytest.fixture
+def tracer():
+    """A Tracer installed ambiently for one test, then uninstalled."""
+    before = ambient_tracer()
+    tr = Tracer()
+    set_ambient_tracer(tr)
+    yield tr
+    set_ambient_tracer(before)
+
+
+def _structure(tr, keep):
+    """``tr.structure()`` of the kept spans only, each at the depth of
+    its kept ancestors."""
+    by_seq = {e["seq"]: e for e in tr.events}
+    out = []
+    for e in sorted(tr.events, key=lambda e: e["seq"]):
+        if keep(e["name"]):
+            depth, p = 0, e["parent"]
+            while p is not None:
+                depth += keep(by_seq[p]["name"])
+                p = by_seq[p]["parent"]
+            out.append((depth, e["name"]))
+    return out
 
 
 # --------------------------------------------------------------------------- #
 # tracer unit behavior
 # --------------------------------------------------------------------------- #
 
-def test_tracer_nesting_and_clock():
-    tr = Tracer()
-    with tr.span("window", n=2) as w:
-        with tr.span("query", dur=0.5, query="Q1"):
-            pass
-        with tr.span("query", dur=0.25, query="Q2"):
+def test_tracer_nesting_and_clock(tracer):
+    t0 = time.time_ns()
+    with span("window", n=2) as w:
+        with span("query", query="Q1"):
+            time.sleep(0.002)
+        with span("query", query="Q2"):
             pass
         w.annotate(late=True)
-    # siblings lay out sequentially; the dur=0 parent covers its children
-    q1, q2 = tr.find("query")
-    assert (q1["ts"], q1["dur"]) == (0.0, 0.5)
-    assert (q2["ts"], q2["dur"]) == (0.5, 0.25)
-    (win,) = tr.find("window")
-    assert win["ts"] == 0.0 and win["dur"] == pytest.approx(0.75)
-    assert win["args"] == {"n": 2, "late": True}
-    assert tr.now == pytest.approx(0.75)
-    # depth reflects the open stack; structure is open-order
-    assert tr.structure() == [(0, "window"), (1, "query"), (1, "query")]
-
-    tr.advance_to(2.0)
-    assert tr.now == 2.0
-    tr.advance_to(1.0)                  # monotone: never rewinds
-    assert tr.now == 2.0
-    with tr.span("query", dur=0.1):
+    with span("round"):
         pass
-    assert tr.find("query")[-1]["ts"] == 2.0
+    t1 = time.time_ns()
+    win, = tracer.find("window")
+    q1, q2 = tracer.find("query")
+    rnd, = tracer.find("round")
+    # parent seq and one request id per outermost span
+    assert win["parent"] is None and q1["parent"] == q2["parent"] == win["seq"]
+    assert q1["req"] == q2["req"] == win["req"] != rnd["req"]
+    assert win["args"] == {"n": 2, "late": True}
+    # real time on the wall clock: children inside the parent, in order
+    assert t0 <= win["ts_ns"] <= q1["ts_ns"]
+    assert q1["dur_ns"] >= 2_000_000
+    assert q1["ts_ns"] + q1["dur_ns"] <= q2["ts_ns"]
+    assert q2["ts_ns"] + q2["dur_ns"] <= win["ts_ns"] + win["dur_ns"] <= t1
+    # depth reflects the open stack; structure is open-order
+    assert tracer.structure() == [(0, "window"), (1, "query"), (1, "query"),
+                                  (0, "round")]
+    assert _structure(tracer, lambda n: n != "window") == [
+        (0, "query"), (0, "query"), (0, "round")]
 
 
-def test_tracer_chrome_export_schema(tmp_path):
-    tr = Tracer()
-    with tr.span("adapt.round", cat="adapt", trigger="explicit") as sp:
-        with tr.span("migration.chunk", cat="migrate", dur=0.125, bytes=96):
+def test_tracer_chrome_export_schema(tracer, tmp_path):
+    with span("repro.adapt.round", trigger="explicit") as sp:
+        with span("repro.migrate.chunk", bytes=96):
             pass
         sp.annotate(accepted=True)
-    raw = tr.chrome_trace()
+    raw = tracer.chrome_trace()
     assert raw["displayTimeUnit"] == "ms"
     phases = [e["ph"] for e in raw["traceEvents"]]
-    assert phases.count("M") == 2 and phases.count("X") == len(tr.events)
-    for ev in raw["traceEvents"]:
-        assert {"name", "ph", "pid", "tid"} <= set(ev)
-        if ev["ph"] == "X":
-            assert ev["ts"] >= 0 and ev["dur"] >= 0      # microseconds
-    chunk = next(e for e in raw["traceEvents"]
-                 if e["name"] == "migration.chunk")
-    assert chunk["dur"] == pytest.approx(0.125e6)
-
+    assert phases.count("M") == 1 and phases.count("X") == len(tracer.events)
+    rnd, chunk = [e for e in raw["traceEvents"] if e["ph"] == "X"]
+    for ev in (rnd, chunk):
+        assert {"name", "ph", "ts", "dur", "pid", "tid", "args"} <= set(ev)
+        assert ev["dur"] >= 0 and ev["ts"] > 1e15      # epoch microseconds
+        assert {"seq", "parent", "req"} <= set(ev["args"])
+    assert chunk["args"]["parent"] == rnd["args"]["seq"]
+    assert chunk["args"]["req"] == rnd["args"]["req"]
+    assert rnd["args"]["accepted"] is True and chunk["args"]["bytes"] == 96
+    assert rnd["ts"] <= chunk["ts"]
     p = tmp_path / "t.json"
-    assert tr.export(str(p)) == len(tr.events) == 2
-    assert json.loads(p.read_text()) == json.loads(tr.to_json())
-    pl = tmp_path / "t.jsonl"
-    assert tr.export(str(pl)) == 2
-    lines = [json.loads(s) for s in pl.read_text().splitlines()]
-    # JSONL is in span *open* (seq) order, not close order
-    assert [e["name"] for e in lines] == ["adapt.round", "migration.chunk"]
+    assert tracer.export(str(p)) == len(tracer.events) == 2
+    assert json.loads(p.read_text()) == json.loads(json.dumps(raw))
 
 
-def test_tracer_attrs_json_safe():
+def test_tracer_attrs_json_safe(tracer):
     import numpy as np
-    tr = Tracer()
-    with tr.span("x", a=np.int32(3), b=np.float64(0.5), c=(1, np.int64(2)),
-                 d={"k": np.bool_(True)}, e=None):
+    with span("x", a=np.int32(3), b=np.float64(0.5), c=(1, np.int64(2)),
+              d={"k": np.bool_(True)}, e=None):
         pass
-    (ev,) = tr.events
+    (ev,) = tracer.events
     assert ev["args"] == {"a": 3, "b": 0.5, "c": [1, 2],
                           "d": {"k": True}, "e": None}
     json.dumps(ev["args"])              # round-trips without a custom encoder
 
 
 def test_null_tracer_is_inert():
+    before = ambient_tracer()
+    set_ambient_tracer(NULL_TRACER)
     tr = NULL_TRACER
-    assert isinstance(tr, NullTracer) and not tr.enabled
-    with tr.span("query", dur=1.0, big=list(range(10))) as sp:
+    assert not tr.enabled
+    with span("query", big=list(range(10))) as sp:
+        assert not sp.recording
         sp.annotate(x=1)
-    tr.instant("mark")
-    tr.advance_to(99.0)
+    assert sp.event is None and "x" not in sp.attrs
     assert len(tr) == 0 and tr.structure() == [] and tr.span_counts() == {}
-    assert tr.find("query") == [] and tr.now == 0.0
+    assert tr.find("query") == []
+    set_ambient_tracer(before)
+
+
+def test_spans_accumulate_in_the_ambient_registry():
+    from repro.obs import ambient
+    before = ambient()
+    m = MetricsRegistry()
+    set_ambient(m)
+    with span("repro.exec.scan"):
+        time.sleep(0.001)
+    with span("repro.exec.scan"):
+        pass
+    set_ambient(before)
+    c = m.snapshot()["counters"]
+    assert c["span.repro.exec.scan.calls"] == 2
+    assert c["span.repro.exec.scan.ns"] >= 1_000_000
 
 
 # --------------------------------------------------------------------------- #
@@ -222,46 +265,63 @@ def _traced_drain(ds, executor="numpy"):
     return svc, windows, len(window)
 
 
+def _serve_level(name):
+    return (name.startswith("repro.serve.")
+            or name in ("repro.exec.query", "repro.adapt.round"))
+
+
 def test_traced_drain_is_complete_and_metered(small_lubm):
     svc, windows, per_window = _traced_drain(small_lubm)
-    counts = svc.tracer().span_counts()
+    tr = svc.tracer()
+    counts = tr.span_counts()
     served = windows * per_window
-    # every query decomposes plan -> scan -> join -> federate -> ship
-    for leg in ("plan", "scan", "join", "federate", "ship"):
-        assert counts[leg] == counts["query"] == served
-    assert counts["window"] == windows
-    assert counts["adapt.round"] == 1
-    assert counts["migration.chunk"] >= 3
-    (rnd,) = svc.tracer().find("adapt.round")
+    m = svc.stats()["metrics"]
+    # every window, round and chunk is recorded; each miss is one query
+    assert counts["repro.serve.window"] == windows
+    assert counts["repro.adapt.round"] == 1
+    assert counts["repro.migrate.chunk"] == m["counters"]["migrate.chunks"]
+    assert counts["repro.migrate.chunk"] >= 3
+    misses = served - m["counters"].get("queries.result_cache_hits", 0)
+    assert counts["repro.exec.query"] == misses
+    assert sum(w["args"]["misses"] for w in tr.find("repro.serve.window")) \
+        == misses
+    (rnd,) = tr.find("repro.adapt.round")
     assert rnd["args"]["accepted"] is True
     assert rnd["args"]["trigger"] == "explicit"
     assert rnd["args"]["reason"] in ("amortized", "improved")
     assert rnd["args"]["t_new"] < rnd["args"]["t_base"]
-    # a query span's children tile its modeled duration exactly
-    tr = svc.tracer()
-    q = next(e for e in tr.events if e["name"] == "query")
-    kids = [e for e in tr.events
-            if e["name"] in ("plan", "scan", "join", "federate", "ship")
-            and q["ts"] <= e["ts"] and e["ts"] + e["dur"] <= q["ts"]
-            + q["dur"] + 1e-12]
-    assert sum(k["dur"] for k in kids[:5]) == pytest.approx(q["dur"])
-    m = svc.stats()["metrics"]
+    assert counts["repro.adapt.measure"] >= 2     # baseline + candidate
+    by_seq = {e["seq"]: e for e in tr.events}
+    for e in tr.events:                 # children nest inside their parent
+        if e["parent"] is not None:
+            p = by_seq[e["parent"]]
+            assert e["req"] == p["req"]
+            assert p["ts_ns"] <= e["ts_ns"]
+            assert e["ts_ns"] + e["dur_ns"] <= p["ts_ns"] + p["dur_ns"]
+    for w in tr.find("repro.serve.window"):      # one request per window
+        kids = [e for e in tr.events if e["req"] == w["req"]]
+        assert all(e["name"] != "repro.serve.window" or e is w for e in kids)
+    q = tr.find("repro.exec.query")[0]["args"]
+    assert q["modeled_s"] > 0 and q["query"] and q["rows"] >= 0
     assert m["counters"]["queries.served"] == served
-    assert m["counters"]["migrate.chunks"] == counts["migration.chunk"]
     assert m["counters"]["adapt.accepted"] == 1
-    assert m["histograms"]["query.modeled_s"]["n"] == served
+    assert m["histograms"]["query.modeled_s"]["n"] == misses
     assert m["gauges"]["migrate.progress"] == 1.0
     assert m["counters"]["federation.bytes_shipped"] > 0
+    assert m["counters"]["span.repro.serve.window.calls"] == windows
     # kernel dispatch tier picks landed in the ambient registry
     assert any(k.startswith("kernels.dispatch.jaccard.distance.")
                for k in m["counters"])
 
 
 def test_trace_byte_identical_same_seed(small_lubm):
+    """Two same-seed runs open the same spans in the same nesting; the
+    times differ, so the files no longer match byte for byte."""
     a, _, _ = _traced_drain(small_lubm)
     b, _, _ = _traced_drain(small_lubm)
-    assert a.tracer().to_json() == b.tracer().to_json()
-    assert a.tracer().to_jsonl() == b.tracer().to_jsonl()
+    assert a.tracer().structure() == b.tracer().structure()
+    assert [e["req"] for e in a.tracer().events] == \
+        [e["req"] for e in b.tracer().events]
 
 
 def test_trace_structure_identical_across_executors(small_lubm):
@@ -269,12 +329,10 @@ def test_trace_structure_identical_across_executors(small_lubm):
     for name in EXECUTORS:
         svc, _, _ = _traced_drain(small_lubm, executor=name)
         traces[name] = svc.tracer()
-    ref = traces["numpy"]
+    ref = _structure(traces["numpy"], _serve_level)
+    assert ref.count((0, "repro.adapt.round")) == 1
     for name in EXECUTORS[1:]:
-        assert traces[name].structure() == ref.structure(), name
-        # modeled durations derive from ExecStats.COMPARABLE, pinned
-        # identical across backends -> the whole trace is byte-identical
-        assert traces[name].to_json() == ref.to_json(), name
+        assert _structure(traces[name], _serve_level) == ref, name
 
 
 def test_untraced_service_records_nothing(small_lubm):
@@ -282,15 +340,49 @@ def test_untraced_service_records_nothing(small_lubm):
     svc.bootstrap(small_lubm.base_workload())
     svc.query_batch(small_lubm.extended_workload())
     assert isinstance(svc._tracer, NullTracer)
+    assert ambient_tracer() is svc._tracer
     assert len(svc._tracer) == 0
-    # ...but the metrics registry is always live
-    assert svc.stats()["metrics"]["counters"]["queries.served"] > 0
+    # ...but the metrics registry is always live, span totals included
+    counters = svc.stats()["metrics"]["counters"]
+    assert counters["queries.served"] > 0
+    assert counters["span.repro.serve.window.calls"] == 1
+
+
+def test_spans_reach_the_profilers_host_plane(small_lubm, tmp_path):
+    """A tiny service served under ``jax.profiler.trace``: the capture's
+    host plane holds ``repro.serve.window`` with ``repro.exec.scan`` nested
+    inside it, on the clock the Tracer records (CLOCK_REALTIME, offset by
+    the capture's ``profile_start_time``)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    svc = KGService.from_dataset(small_lubm, n_shards=4, executor="jax",
+                                 trace=True)
+    svc.bootstrap(small_lubm.base_workload())
+    with jax.profiler.trace(str(tmp_path)):
+        svc.serve_window([small_lubm.queries["Q1"]])
+    path, = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                      recursive=True)
+    pd = ProfileData.from_file(path)
+    env = dict(next(p for p in pd.planes
+                    if p.name == "Task Environment").stats)
+    events = [(e.name, e.start_ns, e.duration_ns)
+              for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for e in line.events
+              if e.name.startswith("repro.")]
+    (_, w0, wd), = [e for e in events if e[0] == "repro.serve.window"]
+    scans = [e for e in events if e[0] == "repro.exec.scan"]
+    assert scans and all(w0 <= s and s + d <= w0 + wd for _, s, d in scans)
+    mine, = svc.tracer().find("repro.serve.window")
+    # the two clocks agree to well under a millisecond
+    assert abs(env["profile_start_time"] + w0 - mine["ts_ns"]) < 1e6
+    assert abs(wd - mine["dur_ns"]) < 1e6
 
 
 def test_traced_flash_crowd_scenario():
     """A traced drift replay captures the reaction end-to-end: the round
-    the controller fires, its drain, and every served query — and stays
-    byte-identical across two same-seed replays."""
+    the controller fires, its drain, and every served window — and two
+    same-seed replays open the same spans at the serving level."""
     from repro import scenario as drift
     from repro.graph import watdiv
 
@@ -312,11 +404,13 @@ def test_traced_flash_crowd_scenario():
     counts = svc.tracer().span_counts()
     # every reacted window is covered by a recorded round (warm-up and
     # rejected rounds may add more)
-    assert counts["adapt.round"] >= sum(1 for w in rep.windows if w.adapted)
-    assert counts["query"] > 0 and counts["window"] > 0
-    rounds = svc.tracer().find("adapt.round")
+    assert counts["repro.adapt.round"] >= sum(1 for w in rep.windows
+                                              if w.adapted)
+    assert counts["repro.exec.query"] > 0 and counts["repro.serve.window"] > 0
+    rounds = svc.tracer().find("repro.adapt.round")
     assert all(r["args"]["trigger"] in ("degradation", "write_drift",
                                         "no_baseline", "explicit")
                for r in rounds)
     svc2, _ = run()
-    assert svc2.tracer().to_json() == svc.tracer().to_json()
+    assert _structure(svc2.tracer(), _serve_level) == \
+        _structure(svc.tracer(), _serve_level)

@@ -96,7 +96,7 @@ def test_expand_pairs_kernel_compiles(one_chip, segments, total):
 def test_gather_stage_compiles(one_chip):
     """The pipeline's gather stage is XLA's device gather (no Pallas
     kernel: Mosaic lowers only 2-D gathers)."""
-    c = _compile(ops._pipe_fns()["take"], one_chip,
+    c = _compile(ops._pipe_fns()["pair_gather"], one_chip,
                  ((WIDE_BUILD,), jnp.int32), ((MAX_ROWS,), jnp.int32))
     assert c.memory_analysis().output_size_in_bytes >= MAX_ROWS * 4
 
