@@ -14,7 +14,10 @@ invalidate, because the PPN choice and federation annotations are
 layout-dependent). Layout-invariant ``QueryProfile``s are derived from the
 plan and cached separately — they survive commits, which is what makes
 candidate evaluation (``measure_candidate``) pure bincount re-accounting
-with no joins re-executed and no views touched.
+with no joins re-executed and no views touched. An executor that matches
+against the global store hands over each query's profile as a by-product
+of serving it (``note_profile``); only queries it never ran are profiled
+by a host execution of their own.
 
 Beside the primary assignment the facade carries a
 ``repro.replicate.ReplicaMap``: shard views additionally materialize any
@@ -378,7 +381,9 @@ class PartitionedKG:
 
     def profile(self, q: Query) -> qplan.QueryProfile:
         """Layout-invariant execution profile of ``q``, derived from its plan
-        (cached; one real execution against the global store on first use).
+        (cached: noted by the serving executor when it ran the query, see
+        ``note_profile``, else one real execution against the global store
+        on first use).
         Survives layout epochs but not writes — profiles hold global row
         ids of the triples the query matched."""
         pats = tuple(q.patterns)
@@ -388,12 +393,45 @@ class PartitionedKG:
                                                    self.max_join_rows),
                      self.data_version)
             self._profiles[q.name] = entry
+            self.metrics.counter("cache.profile_builds").inc()
         else:
             assert entry[2] == self.data_version, \
                 f"stale profile served for {q.name}: cached at data " \
                 f"version {entry[2]}, store is at {self.data_version} — a " \
                 "write path skipped profile invalidation"
+            self.metrics.counter("cache.profile_hits").inc()
         return entry[1]
+
+    def note_profile(self, plan: qplan.QueryPlan,
+                     pattern_rows: List[np.ndarray], stats: qexec.ExecStats,
+                     max_join_rows: int) -> None:
+        """Adopt an executor's by-product as ``plan.query``'s profile, so
+        that ``profile`` need not execute the query again. An executor that
+        matches against the global store calls this after each query it
+        ran: ``pattern_rows`` are the row ids it matched per executed op
+        (kept as they are, marked read-only, since queries of one batch
+        share them) and ``stats`` its join counts. They are the numbers
+        ``profile_from_plan`` computes, provided the plan is the one this
+        facade serves now (the op order depends on the store's counts) and
+        the executor's cap is no looser than the profiler's (so nothing it
+        ran would have raised here)."""
+        q = plan.query
+        entry = self._profiles.get(q.name)
+        if entry is not None and entry[0] == tuple(q.patterns):
+            return
+        served = self._plans.get(q.name)
+        if served is None or served[1] is not plan \
+                or max_join_rows > self.max_join_rows:
+            return
+        for idx in pattern_rows:
+            idx.setflags(write=False)
+        prof = qplan.QueryProfile(
+            pattern_rows=pattern_rows, join_rows=stats.join_rows,
+            rows=stats.rows, n_patterns=plan.n_patterns,
+            cartesian_rows=stats.cartesian_rows,
+            expanded_rows=stats.expanded_rows)
+        self._profiles[q.name] = (tuple(q.patterns), prof, self.data_version)
+        self.metrics.counter("cache.profile_noted").inc()
 
     def cached_result(self, q: Query,
                       ) -> Optional[Tuple[dict, qexec.ExecStats]]:

@@ -475,6 +475,10 @@ class JaxExecutor:
         # pattern -> (row ids, matched triples, variable columns)
         match_cache: Dict[tuple, tuple] = {}
 
+        # a facade keeps each query's profile: the row ids matched per
+        # executed op and the join counts, which this loop computes anyway
+        note_profile = getattr(kg, "note_profile", None)
+
         results: List[Tuple[Bindings, ExecStats]] = []
         query_spans = []
         executed: List[Tuple[int, tuple]] = []         # (query, pattern)
@@ -482,7 +486,7 @@ class JaxExecutor:
             with span("repro.exec.query") as qsp:
                 stats = ExecStats()
                 table: Optional[Bindings] = None
-                ops_run = 0
+                pattern_rows: List[np.ndarray] = []
                 for op in plan.ops:
                     hit = match_cache.get(op.pattern)
                     if hit is None:
@@ -499,19 +503,21 @@ class JaxExecutor:
                         match_cache[op.pattern] = hit
                     idx, rows, cols = hit
                     executed.append((qi, op.pattern))
-                    ops_run += 1
+                    pattern_rows.append(idx)
                     before = _table_len(table)
                     table = _join_jax(table, op.pattern, rows, stats,
                                       self.max_join_rows, probe, cols=cols)
                     stats.join_rows += before + len(rows) + _table_len(table)
                     if table is not None and _table_len(table) == 0:
                         break
-                if table is not None and ops_run == 1:
+                if table is not None and len(pattern_rows) == 1:
                     # single-op result IS the cached column dict: copy so
                     # two queries in the window never alias the same
                     # binding arrays
                     table = {v: c.copy() for v, c in table.items()}
                 stats.rows = _table_len(table)
+            if note_profile is not None:
+                note_profile(plan, pattern_rows, stats, self.max_join_rows)
             query_spans.append(qsp)
             results.append((table or {}, stats))
 
@@ -594,6 +600,9 @@ def profile_from_plan(plan: qplan.QueryPlan, store,
                       ) -> qplan.QueryProfile:
     """One real execution of ``plan`` against the global store, recording the
     layout-invariant artifacts (matched row ids, join-pipeline counts).
+    ``JaxExecutor`` computes the same artifacts while it serves a query and
+    hands them to the facade (``PartitionedKG.note_profile``), so this runs
+    only for queries no such executor has run since the last write.
     ``max_join_rows`` should match the serving executor's cap so profiling
     never rejects a workload the executor was configured to allow.
 
