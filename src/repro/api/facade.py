@@ -362,10 +362,13 @@ class PartitionedKG:
     def plan(self, q: Query) -> qplan.QueryPlan:
         """The query's execution plan under the current layout (cached per
         ``(query, store)``; invalidated by ``commit``/``sync_universe``/
-        ``apply_write``)."""
+        ``apply_write``). A plan built while the store holds no predicate
+        statistics builds them, which counts as ``plan.stats_builds``."""
         pats = tuple(q.patterns)
         entry = self._plans.get(q.name)
         if entry is None or entry[0] != pats:
+            if not self.store.has_predicate_stats:
+                self.metrics.counter("plan.stats_builds").inc()
             entry = (pats, qplan.plan(q, self), self.epoch)
             self._plans[q.name] = entry
             self.plan_builds += 1

@@ -48,6 +48,16 @@ def _sort_index(triples: np.ndarray, order: Tuple[int, int, int]) -> np.ndarray:
     return np.lexsort(keys).astype(np.int64)
 
 
+def _runs(view: np.ndarray, cols: Sequence[int]) -> np.ndarray:
+    """Mask of the rows of a sorted ``view`` that start a new run of equal
+    values in ``cols``."""
+    new = np.zeros(view.shape[0], dtype=bool)
+    new[:1] = True
+    for c in cols:
+        new[1:] |= view[1:, c] != view[:-1, c]
+    return new
+
+
 @dataclasses.dataclass
 class TripleStore:
     """Immutable dictionary-encoded triple set with SPO/POS/OSP indexes."""
@@ -68,6 +78,7 @@ class TripleStore:
         if self.osp is None:
             self.osp = _sort_index(self.triples, (O, S, P))
         self._sorted_views: Dict[str, np.ndarray] = {}
+        self._predicate_stats: Optional[Dict[int, Tuple[int, int]]] = None
 
     def _sorted_view(self, which: str) -> np.ndarray:
         """A permutation's sorted triple matrix, materialized lazily on the
@@ -137,6 +148,36 @@ class TripleStore:
     def count(self, s: Optional[int], p: Optional[int], o: Optional[int]) -> int:
         return int(self.match_indices(s, p, o).shape[0])
 
+    @property
+    def has_predicate_stats(self) -> bool:
+        """Whether :meth:`predicate_stats` is built for the current
+        triples (it is dropped by every effective write)."""
+        return self._predicate_stats is not None
+
+    def predicate_stats(self) -> Dict[int, Tuple[int, int]]:
+        """``{p: (distinct subjects, distinct objects)}`` for every
+        predicate id: the planner's join-size statistics. Built on first
+        use in one pass over each of the POS and SPO sorted views (a run
+        of equal ``(p, o)`` there is one distinct object, a run of equal
+        ``(s, p)`` one distinct subject; no sort) and kept until
+        ``apply_mutation`` changes the triples."""
+        if self._predicate_stats is None:
+            pos = self._sorted_view("pos")
+            spo = self._sorted_view("spo")
+            preds = pos[_runs(pos, (P,)), P]
+
+            def per_predicate(view, cols):
+                run_preds = view[_runs(view, cols), P]
+                return np.bincount(np.searchsorted(preds, run_preds),
+                                   minlength=len(preds))
+
+            n_subjects = per_predicate(spo, (S, P))
+            n_objects = per_predicate(pos, (P, O))
+            self._predicate_stats = {
+                int(p): (int(ns), int(no))
+                for p, ns, no in zip(preds, n_subjects, n_objects)}
+        return self._predicate_stats
+
     # ------------------------------------------------------------------ #
     # live mutation (repro.write)
     # ------------------------------------------------------------------ #
@@ -144,7 +185,8 @@ class TripleStore:
                        delete_rows: np.ndarray) -> np.ndarray:
         """Mutate the store in place: drop the rows in ``delete_rows``
         (global row ids), append ``inserts`` ((M, 3) int32 triples assumed
-        not already present), and rebuild the SPO/POS/OSP permutations.
+        not already present), and rebuild the SPO/POS/OSP permutations
+        (the predicate statistics go with them).
 
         Mutating *in place* is what keeps every holder of this store object
         (``FeatureSpace.store``, ``KGService.store``, the facade and its
@@ -175,6 +217,7 @@ class TripleStore:
             self.pos = _sort_index(self.triples, (P, O, S))
             self.osp = _sort_index(self.triples, (O, S, P))
             self._sorted_views.clear()
+            self._predicate_stats = None
         return remap
 
 

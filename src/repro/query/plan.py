@@ -33,6 +33,7 @@ from typing import Dict, List, Sequence, Tuple
 import numpy as np
 
 from repro.core.migration import TRIPLE_BYTES
+from repro.obs import span
 from repro.query.pattern import Pattern, Query, is_var
 
 
@@ -84,6 +85,7 @@ class PlanOp:
     pattern: Pattern
     est_rows: int                  # global match count (selectivity estimate)
     selectivity: float             # est_rows / total triples
+    est_table: int                 # estimated binding rows after this op
     join_vars: Tuple[int, ...]     # vars shared with the table built so far
     new_vars: Tuple[int, ...]      # vars first bound by this op
     cartesian: bool                # no shared vars: cross product (capped)
@@ -118,7 +120,8 @@ class QueryPlan:
                      else f"SERVICE shard{op.home}")
             lines.append(f"  [{i}] {op.pattern} {kind} "
                          f"~{op.est_rows} rows "
-                         f"(sel={op.selectivity:.2e}) {where}")
+                         f"(sel={op.selectivity:.2e}) -> ~{op.est_table} "
+                         f"table rows {where}")
         return "\n".join(lines)
 
 
@@ -131,21 +134,65 @@ def _resolve_source(stats_source) -> Tuple[object, object, object, object]:
     return store, space, state, replicas
 
 
-def _join_order(patterns: Sequence[Pattern],
-                counts: Dict[Pattern, int]) -> List[Pattern]:
-    """Greedy join order: most selective first, staying connected."""
+def _distinct(pat: Pattern, count: int,
+              predicate_stats: Dict[int, Tuple[int, int]]) -> Dict[int, int]:
+    """Distinct values each variable of ``pat`` takes over its ``count``
+    matches, estimated: under a bound predicate, a variable subject or
+    object takes the predicate's distinct subjects or objects, capped at
+    ``count``; any other position takes ``count``."""
+    s, p, o = pat
+    n_subjects, n_objects = (predicate_stats.get(p, (0, 0))
+                             if not is_var(p) else (count, count))
+    out: Dict[int, int] = {}
+    for v, n in ((s, n_subjects), (p, count), (o, n_objects)):
+        if is_var(v):
+            out[v] = min(out.get(v, count), n, count)
+    return out
+
+
+def _join_order(patterns: Sequence[Pattern], counts: Dict[Pattern, int],
+                predicate_stats: Dict[int, Tuple[int, int]],
+                ) -> Tuple[List[Pattern], List[float]]:
+    """Greedy join order by estimated intermediate size.
+
+    The first op is the pattern with the fewest matches. Each next op is
+    the connected pattern P whose join with the table T built so far has
+    the smallest estimated size |T|·|P| / ∏ max(V(T, v), V(P, v)) over
+    their shared variables v, ties going to the fewest matches. V(P, v)
+    is :func:`_distinct`'s; V(T, v) is the least V of any joined pattern
+    binding v, capped at |T|. A pattern sharing no variable with the table
+    comes only when no connected one remains (a cartesian op, |T|·|P|).
+    Returns the order and the estimated table size after each op."""
     remaining = list(patterns)
-    bound_vars: set = set()
+    distinct = {p: _distinct(p, counts[p], predicate_stats)
+                for p in remaining}
+    seen: Dict[int, int] = {}          # least V of each bound variable
+    table = 1.0                        # before any op: one empty binding
     order: List[Pattern] = []
+    sizes: List[float] = []
+
+    def estimate(pat: Pattern) -> float:
+        out = table * counts[pat]
+        for v, n in distinct[pat].items():
+            if v in seen:
+                out /= max(min(seen[v], table), n, 1)
+        return out
+
     while remaining:
         connected = [p for p in remaining
-                     if any(is_var(s) and s in bound_vars for s in p)]
-        pool = connected if connected and bound_vars else remaining
-        pick = min(pool, key=lambda p: counts[p])
+                     if any(v in seen for v in distinct[p])]
+        if connected:
+            pick = min(connected, key=lambda p: (estimate(p), counts[p]))
+            table = estimate(pick)
+        else:
+            pick = min(remaining, key=lambda p: counts[p])
+            table *= counts[pick]
         order.append(pick)
+        sizes.append(table)
         remaining.remove(pick)
-        bound_vars.update(s for s in pick if is_var(s))
-    return order
+        for v, n in distinct[pick].items():
+            seen[v] = min(seen.get(v, n), n)
+    return order, sizes
 
 
 def plan(q: Query, stats_source) -> QueryPlan:
@@ -155,7 +202,9 @@ def plan(q: Query, stats_source) -> QueryPlan:
                                None if is_var(pat[1]) else pat[1],
                                None if is_var(pat[2]) else pat[2])
               for pat in q.patterns}
-    order = _join_order(q.patterns, counts)
+    with span("repro.plan.order"):
+        order, sizes = _join_order(q.patterns, counts,
+                                   store.predicate_stats())
     federated = space is not None and state is not None
     ppn = primary_shard(q, space, state, replicas) if federated else 0
     n_shards = state.n_shards if federated else 1
@@ -171,6 +220,7 @@ def plan(q: Query, stats_source) -> QueryPlan:
                 if federated else 0)
         ops.append(PlanOp(pattern=pat, est_rows=counts[pat],
                           selectivity=counts[pat] / total,
+                          est_table=int(round(sizes[i])),
                           join_vars=join_vars, new_vars=new_vars,
                           cartesian=i > 0 and not join_vars,
                           home=home,
